@@ -12,12 +12,16 @@ results.  This smoke proves it end to end, per algorithm:
   2. the child mutates again and starts an incremental ``save_dirty``,
      with a hook that SIGKILLs the process after the second leaf write —
      a torn ``step_1.tmp-<pid>`` dir with no manifest is left behind;
-  3. the parent verifies the child died by SIGKILL and the torn tmp
-     exists, builds an UNKILLED TWIN (same seeds, same mutation history
+  3. once every algorithm's child has run, the parent verifies each child
+     died by SIGKILL and left its torn tmp, builds an UNKILLED TWIN (same seeds, same mutation history
      up to the committed step), loads the checkpoint
      (``ShardedKNNStore.load`` — must resolve step 0, ignoring the torn
      write), and asserts ids AND scores of a query batch are bit-equal
      to the twin's, with ZERO query-time index builds after load.
+
+The parent touches JAX only after the last child has exited: a process
+that has initialized a backend holds the accelerator, and a child started
+after that could not get the device.
 
   XLA_FLAGS=--xla_force_host_platform_device_count=4 \
       PYTHONPATH=src python -m benchmarks.crash_smoke        # make crash-smoke
@@ -86,11 +90,9 @@ def child(directory: str, algorithm: str, kill_after: int = 2) -> None:
     raise SystemExit("kill hook never fired — save wrote no leaves?")
 
 
-def run_one(algorithm: str, base_dir: str) -> dict:
-    from repro.checkpoint import ckpt as _ckpt
-    from repro.sparse.datagen import synthetic_sparse
-    from repro.store import ShardedKNNStore
-
+def crash_one(algorithm: str, base_dir: str) -> dict:
+    """Run the killed-mid-save child for one algorithm.  No JAX here: the
+    child must be the only process on the device."""
     d = os.path.join(base_dir, algorithm)
     t0 = time.perf_counter()
     proc = subprocess.run(
@@ -102,6 +104,19 @@ def run_one(algorithm: str, base_dir: str) -> dict:
     if not killed:
         sys.stderr.write(proc.stderr[-2000:] + "\n")
     torn = os.path.isdir(d) and any(".tmp-" in n for n in os.listdir(d))
+    return {"dir": d, "killed": killed, "torn": torn,
+            "child_s": time.perf_counter() - t0}
+
+
+def verify_one(algorithm: str, crash: dict) -> dict:
+    """Warm-restart the crashed child's checkpoint and check it against an
+    unkilled twin (this process's first use of JAX)."""
+    from repro.checkpoint import ckpt as _ckpt
+    from repro.sparse.datagen import synthetic_sparse
+    from repro.store import ShardedKNNStore
+
+    d, killed, torn = crash["dir"], crash["killed"], crash["torn"]
+    t0 = time.perf_counter()
     step = _ckpt.latest_step(d) if os.path.isdir(d) else None
 
     twin = scenario(algorithm)                        # unkilled twin
@@ -129,7 +144,7 @@ def run_one(algorithm: str, base_dir: str) -> dict:
         "live_rows": int(loaded.num_vectors),
         "shards": loaded.n_shards,
         "load_s": round(load_s, 4),
-        "wall_s": round(time.perf_counter() - t0, 4),
+        "wall_s": round(crash["child_s"] + time.perf_counter() - t0, 4),
         **checks,
         "ok": all(checks.values()),
     }
@@ -149,10 +164,11 @@ def main(argv=None) -> int:
         child(args.child, args.algorithm or "iib")
         return 1                                      # unreachable
 
-    records = []
+    algorithms = [a.strip() for a in args.algorithms.split(",")]
     with tempfile.TemporaryDirectory(prefix="crash_smoke_") as base:
-        for algorithm in args.algorithms.split(","):
-            records.append(run_one(algorithm.strip(), base))
+        # every child first: the parent may not hold a device while one runs
+        crashes = [crash_one(a, base) for a in algorithms]
+        records = [verify_one(a, c) for a, c in zip(algorithms, crashes)]
     ok = all(r["ok"] for r in records)
     print(json.dumps({"crash_smoke": records, "ok": ok}))
     return 0 if ok else 1

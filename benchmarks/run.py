@@ -16,6 +16,7 @@ import sys
 import time
 
 from benchmarks import fig1_data_size, fig2_relative_size, fig3_effect_k, fig4_buffer_size, roofline
+from repro.runtime.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig1_data_size": fig1_data_size.run,
@@ -171,6 +172,7 @@ def perf_record(fast: bool, out_path: str) -> int:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--smoke", action="store_true",

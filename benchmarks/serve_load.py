@@ -56,6 +56,7 @@ import numpy as np
 
 from repro.core import JoinSpec
 from repro.obs import FlightRecorder, fanout_report, set_recorder
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import KNNScheduler, QueueFull, ServeConfig
 from repro.sparse.datagen import synthetic_sparse
 from repro.sparse.format import SparseBatch
@@ -564,6 +565,7 @@ def smoke() -> int:
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI load: completed == submitted, zero "

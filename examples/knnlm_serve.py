@@ -46,6 +46,7 @@ from repro.core import JoinSpec
 from repro.launch.serve import Request, Server
 from repro.models import model as M
 from repro.obs import FlightRecorder, ProfileCapture
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import KNNScheduler, ServeConfig
 from repro.sparse.format import SparseBatch
 from repro.store import ShardedKNNStore
@@ -218,6 +219,7 @@ async def main_async(ckpt: str = None, resume: bool = False,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--ckpt", default=None,
                     help="checkpoint dir: save on build + incrementally "

@@ -14,10 +14,12 @@ import time
 import numpy as np
 
 from repro.core import JoinSpec, JoinStats, SparseKNNIndex
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.sparse.datagen import spectra_like
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nr", type=int, default=500, help="experimental spectra")
     ap.add_argument("--ns", type=int, default=5000, help="library spectra")

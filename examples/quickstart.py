@@ -12,8 +12,11 @@ import numpy as np
 
 from repro.core import JoinSpec, JoinStats, SparseKNNIndex
 from repro.core.reference import oracle_knn
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.sparse.datagen import synthetic_sparse
 from repro.sparse.format import densify
+
+enable_compile_cache()
 
 # 1. a datastore S and two query batches (D = 10,000; ~120 non-zeros each,
 #    the paper's synthetic setting)

@@ -9,9 +9,11 @@ import argparse
 import sys
 
 from repro.launch import train
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true", help="reduced config smoke run")
     ap.add_argument("--steps", type=int, default=0)

@@ -38,7 +38,8 @@ def bf_block_scores(
         rt = densify_tile(r_block, start, dim_chunk)  # (Nr, chunk)
         st = densify_tile(s_block, start, dim_chunk)  # (Ns, chunk)
         return acc + jax.lax.dot_general(
-            rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
 
     acc = jnp.zeros((r_block.num_vectors, s_block.num_vectors), dtype=jnp.float32)

@@ -397,10 +397,15 @@ def _device_batch(host: SparseBatch) -> SparseBatch:
 
 
 def _interpret_kernels() -> bool:
-    """Pallas kernels compile to Mosaic on TPU; elsewhere (CPU tests, this
-    container) they run under interpret mode.  Queried lazily so importing
-    this module never initializes jax device state."""
-    return jax.default_backend() != "tpu"
+    """Pallas kernels compile to Mosaic on TPU and run under interpret mode
+    on the CPU backend (tests); any other backend has no kernel path.
+    Queried lazily so importing this module never initializes jax device
+    state."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"use_kernel needs a TPU (or CPU interpret mode), "
+                           f"not backend {backend!r}")
+    return backend == "cpu"
 
 
 def prepare_r_block_inputs(
@@ -1318,16 +1323,14 @@ class SparseKNNIndex:
         # trace = [seed, after block 0, ..., after block B-1]  (B+1 values)
         return state, {"thr": jnp.concatenate([thr0[None], thr_trace]), "kept": kept}
 
-    def _query_fused_kernel(self, state, br, stats, rb, n_valid, col_cand=None):
-        """One fused score→top-k kernel call covers every S block: scores
-        stream tile-by-tile through VMEM, never materializing in HBM.  The
-        carried state's MinPruneScore seeds the kernel threshold, which
-        then rises in VMEM-resident state across the S grid axis — earlier
-        S blocks prune later ones without ever leaving the device.
-        ``n_valid`` (real rows of a possibly-ragged final R block) keeps
-        padding rows out of the kernel's threshold reduce."""
+    def _fused_kernel_args(self, state, br, rb, n_valid, col_cand=None):
+        """Positional and keyword arguments of ONE fused knn_topk call for
+        a padded R block — shared by the query loop and
+        :meth:`lowered_kernel`, so the two cannot drift apart.  The carried
+        state's MinPruneScore seeds the kernel threshold; ``n_valid`` (real
+        rows of a possibly-ragged final R block) keeps padding rows out of
+        the kernel's threshold reduce."""
         from repro.kernels.knn_score.ops import _pad_rows, active_lists, dense_tiles_with_sentinel
-        from repro.kernels.knn_topk.kernel import knn_topk_pallas
         from repro.kernels.knn_topk.ops import pad_state
 
         ks = self._kernel_stack
@@ -1341,16 +1344,45 @@ class SparseKNNIndex:
         col_valid = ks.col_valid
         if col_cand is not None:
             col_valid = col_valid * col_cand.astype(jnp.int32)
-        out_s, out_i, _ = knn_topk_pallas(
-            r_tiles, ks.s_tiles, active, col_valid, ks.col_ids, init_s, init_i,
+        args = (r_tiles, ks.s_tiles, active, col_valid, ks.col_ids, init_s, init_i)
+        kwargs = dict(
             thr=thr, nr_valid=jnp.full((1,), n_valid, jnp.int32),
             block_r=br_k, block_s=ks.block_s, interpret=_interpret_kernels(),
         )
+        return args, kwargs
+
+    def _query_fused_kernel(self, state, br, stats, rb, n_valid, col_cand=None):
+        """One fused score→top-k kernel call covers every S block: scores
+        stream tile-by-tile through VMEM, never materializing in HBM.  The
+        kernel threshold rises across the S grid axis — earlier S blocks
+        prune later ones without ever leaving the device."""
+        from repro.kernels.knn_topk.kernel import knn_topk_pallas
+
+        args, kwargs = self._fused_kernel_args(state, br, rb, n_valid, col_cand)
+        out_s, out_i, _ = knn_topk_pallas(*args, **kwargs)
         stats.device_dispatches += 1
         stats.blocks += len(self._blocks)
         t_total = num_tiles(self.dim, self.tile)
-        stats.tiles_scored += int((np.asarray(active) < t_total).sum())
+        stats.tiles_scored += int((np.asarray(args[2]) < t_total).sum())
         return TopKState(scores=out_s[:rb], ids=out_i[:rb])
+
+    def lowered_kernel(self, R: SparseBatch):
+        """Lower (without running) the fused knn_topk call of ``R``'s first
+        block, exactly as :meth:`query` makes it — ``as_text()`` shows
+        whether the kernel compiles to Mosaic (``tpu_custom_call``) or runs
+        interpreted."""
+        from repro.kernels.knn_topk.kernel import knn_topk_pallas
+
+        if not (self.spec.use_kernel and self._cache_device
+                and self.algorithm == "iib"):
+            raise ValueError("index has no fused-kernel query path "
+                             "(needs use_kernel, algorithm='iib', cached blocks)")
+        n_r = R.num_vectors
+        rb = min(self.spec.r_block or self.plan_for(R).r_block, n_r)
+        br, _ = _pad_block(R, 0, rb)
+        args, kwargs = self._fused_kernel_args(
+            init_topk(rb, self.spec.k), br, rb, min(rb, n_r))
+        return knn_topk_pallas.lower(*args, **kwargs)
 
     # -- per-pair loops (streaming mode) -------------------------------------
 

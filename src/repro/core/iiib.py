@@ -258,6 +258,7 @@ def iiib_join_block_uniform(
         p = jax.lax.dot_general(
             r_tiles[t], s_tiles[t], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
         return acc + jnp.where(t < c_min, p, 0.0), None
 

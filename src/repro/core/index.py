@@ -258,7 +258,8 @@ def tile_scores(
         rt = r_pad[t]                       # (|Br|, tile)
         v = index.vals[t]                   # (M, tile)
         p = jax.lax.dot_general(
-            rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )                                   # (|Br|, M)
         acc = acc.at[:, index.rows[t]].add(p)
         return acc, None
@@ -306,7 +307,8 @@ def masked_tile_scores(
         rt = r_pad[t]                       # (|Br|, tile)
         v = index.vals[t]                   # (M, tile)
         p = jax.lax.dot_general(
-            rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )                                   # (|Br|, M)
         rows_t = index.rows[t]
         keep_t = kp[rows_t, jnp.minimum(t, t_total)]

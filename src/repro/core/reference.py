@@ -323,3 +323,46 @@ def oracle_knn(dense_r: np.ndarray, dense_s: np.ndarray, k: int) -> Tuple[np.nda
     ids = np.argsort(-scores, axis=1, kind="stable")[:, :k]
     top = np.take_along_axis(scores, ids, axis=1)
     return top, ids
+
+
+def topk_agreement(
+    ref_scores: np.ndarray,
+    ref_ids: np.ndarray,
+    got_scores: np.ndarray,
+    got_ids: np.ndarray,
+    tol: float,
+) -> dict:
+    """How far a top-k answer is from the reference, up to ties within ``tol``.
+
+    Scores must agree within ``tol`` slot by slot (empty ``-inf`` slots
+    must be empty in both).  Ids are compared only at slots whose
+    reference score is more than ``tol`` from both neighbours in its row:
+    within ``tol`` the order of near-equal scores is a matter of rounding,
+    not of correctness.  ``ref_*`` may hold more columns than ``got_*``
+    (compute the reference at depth k + 1): the extra column gives the
+    k-th slot its lower neighbour; without it the k-th slot counts as tied.
+
+    Returns ``max_score_err``, ``ids_checked`` (untied slots) and
+    ``id_mismatches`` (untied slots whose ids differ).
+    """
+    got_s = np.asarray(got_scores, np.float64)
+    got_i = np.asarray(got_ids)
+    k = got_s.shape[1]
+    ref_all = np.asarray(ref_scores, np.float64)
+    ref_s = ref_all[:, :k]
+    ref_i = np.asarray(ref_ids)[:, :k]
+    depth = min(ref_all.shape[1], k + 1)
+    ext = np.full((ref_s.shape[0], k + 2), np.nan)  # ext[:, j + 1] = slot j
+    ext[:, 0] = np.inf
+    ext[:, 1:depth + 1] = ref_all[:, :depth]
+    with np.errstate(invalid="ignore"):
+        empty = np.isneginf(ref_s) & np.isneginf(got_s)
+        err = np.where(empty, 0.0, np.abs(got_s - ref_s))
+        above = ext[:, :k] - ext[:, 1:k + 1]        # gap to the slot above
+        below = ext[:, 1:k + 1] - ext[:, 2:k + 2]   # gap to the slot below
+    untied = (above > tol) & (below > tol)          # NaN gaps count as tied
+    return {
+        "max_score_err": float(err.max(initial=0.0)),
+        "ids_checked": int(untied.sum()),
+        "id_mismatches": int((untied & (got_i != ref_i)).sum()),
+    }

@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core.bf import bf_block_scores
 from repro.core.iiib import iiib_join_block_uniform, prepare_r_block
 from repro.core.index import build_tile_index, dense_r_tiles, tile_scores
@@ -201,8 +200,9 @@ def _ring_join_impl(
         )
 
     out_specs = TopKState(scores=mat_spec, ids=mat_spec)
-    fn = compat.shard_map(
-        local_join, mesh, in_specs=(spec_of(R), spec_of(S)), out_specs=out_specs
+    fn = jax.shard_map(
+        local_join, mesh=mesh, in_specs=(spec_of(R), spec_of(S)),
+        out_specs=out_specs, check_vma=False,
     )
     return fn(R, S)
 

@@ -9,5 +9,5 @@
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public wrapper with padding plumbing), ref.py (pure-jnp oracle).  Kernels
-target TPU; on CPU they run under interpret=True (tests, this container).
+compile for TPU; CPU callers (tests) ask for ``interpret=True`` explicitly.
 """

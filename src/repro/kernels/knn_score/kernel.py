@@ -45,7 +45,8 @@ def _score_kernel(active_ref, r_ref, s_ref, out_ref):
     rt = r_ref[0]  # (block_r, tile)
     st = s_ref[0]  # (block_s, tile)
     out_ref[...] += jax.lax.dot_general(
-        rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

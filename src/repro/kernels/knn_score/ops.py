@@ -2,9 +2,9 @@
 
 ``knn_score(r_block, s_block)`` takes two SparseBatches, densifies them
 into dim-tiles, derives the per-(r-block, s-block) active tile lists from
-occupancy (host- or trace-side), and calls the Pallas kernel.  On CPU
-(tests, this container) ``interpret=True`` executes the kernel body in
-Python; on TPU the same code path compiles to Mosaic.
+occupancy (host- or trace-side), and calls the Pallas kernel.  The kernel
+compiles to Mosaic unless the caller asks for ``interpret=True`` (the CPU
+test path).
 """
 from __future__ import annotations
 
@@ -81,7 +81,7 @@ def knn_score(
     tile: int = 128,
     block_r: int = 256,
     block_s: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """(|Br|, |Bs|) exact dot-product scores via the tile-skipping kernel."""
     from repro.sparse.format import tile_occupancy

@@ -20,19 +20,21 @@ Layout:
   s_valid: (1, NS) int32 — 0 masks padding columns
   s_ids:   (1, NS) int32 — global S id per column
   init_s/init_i: (NR, k) — top-k state to merge into (warm starts compose)
-  thr:     (1, 1) f32 — seed MinPruneScore (a lower bound on every valid
-           row's current k-th score; -inf disables)
+  thr:     (1, 1) f32 in SMEM — seed MinPruneScore (a lower bound on every
+           valid row's current k-th score; -inf disables)
   out:     (NR, k) scores f32 descending + ids i32
-  thr_out: (nR, 1) f32 — per-r-block live MinPruneScore (min over its
-           valid rows' k-th scores), maintained in VMEM-resident state
+  thr_out: (nR, 1) f32 in SMEM — per-r-block live MinPruneScore (min over
+           its valid rows' k-th scores), carried as a scalar across the
+           (nS, A) plane (Mosaic stores no scalar to VMEM)
 
 Grid: (nR, nS, A), all sequential on TPU.  The (block_r, block_s) f32
 accumulator lives in VMEM scratch across the A axis; the (block_r, k)
-state and the (1, 1) threshold live in revisited output blocks across the
-whole (nS, A) plane.  The merge epilogue is the topk_merge insertion body
-(``insert_candidates``) — one constant-depth VPU select/shift pass per
-candidate column, candidate semantics identical to ``topk_update`` on a
-concat (incumbents win ties).
+state lives in a revisited VMEM output block across the whole (nS, A)
+plane; the thresholds are one SMEM-resident (nR, 1) output, row i carried
+as a scalar through r-block i.  The merge epilogue is the topk_merge
+insertion body (``insert_candidates``) — one constant-depth VPU
+select/shift pass per candidate column, candidate semantics identical to
+``topk_update`` on a concat (incumbents win ties).
 
 Candidate rule (IIB, paper Alg. 3 line 14): a column is offered only when
 its accumulated score is > 0 — rows sharing no feature with r are never
@@ -73,7 +75,7 @@ def _knn_topk_kernel(
     def _seed_state():
         out_s_ref[...] = init_s_ref[...]
         out_i_ref[...] = init_i_ref[...]
-        thr_out_ref[0, 0] = thr_ref[0, 0]
+        thr_out_ref[i, 0] = thr_ref[0, 0]
 
     @pl.when(a == 0)
     def _zero_acc():
@@ -82,13 +84,14 @@ def _knn_topk_kernel(
     rt = r_ref[0]  # (block_r, tile)
     st = s_ref[0]  # (block_s, tile)
     acc_ref[...] += jax.lax.dot_general(
-        rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(a == n_a - 1)
     def _merge_epilogue():
         scores = acc_ref[...]                       # (block_r, block_s)
-        thr = thr_out_ref[0, 0]
+        thr = thr_out_ref[i, 0]
         ok = (scores > 0.0) & (valid_ref[0][None, :] > 0) & (scores > thr)
 
         # early exit: a fully-pruned S block never pays the insertion pass
@@ -108,7 +111,7 @@ def _knn_topk_kernel(
                 jnp.int32, (block_r, 1), 0
             )
             kth = new_s[:, -1:]                     # (block_r, 1)
-            thr_out_ref[0, 0] = jnp.min(
+            thr_out_ref[i, 0] = jnp.min(
                 jnp.where(rows < nrv_ref[0], kth, jnp.inf)
             )
 
@@ -153,14 +156,6 @@ def knn_topk_pallas(
         del j, a, active_ref, nrv_ref
         return (i, 0)
 
-    def thr_map(i, j, a, active_ref, nrv_ref):
-        del j, a, active_ref, nrv_ref
-        return (i, 0)
-
-    def const_map(i, j, a, active_ref, nrv_ref):
-        del i, j, a, active_ref, nrv_ref
-        return (0, 0)
-
     return pl.pallas_call(
         _knn_topk_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -173,12 +168,12 @@ def knn_topk_pallas(
                 pl.BlockSpec((1, block_s), col_map),
                 pl.BlockSpec((block_r, k), state_map),
                 pl.BlockSpec((block_r, k), state_map),
-                pl.BlockSpec((1, 1), const_map),
+                pl.BlockSpec(memory_space=pltpu.SMEM),      # whole (1, 1)
             ],
             out_specs=[
                 pl.BlockSpec((block_r, k), state_map),
                 pl.BlockSpec((block_r, k), state_map),
-                pl.BlockSpec((1, 1), thr_map),
+                pl.BlockSpec(memory_space=pltpu.SMEM),      # whole (nR, 1)
             ],
             scratch_shapes=[pltpu.VMEM((block_r, block_s), jnp.float32)],
         ),

@@ -5,9 +5,8 @@ top-k state without materializing the score matrix in HBM: densify into
 dim-tiles, derive the active tile lists from occupancy, and run the fused
 Pallas kernel.  The engine's cached query path skips this wrapper and
 calls ``knn_topk_pallas`` directly on S tiles stacked once at build time
-(one kernel dispatch covers every S block).  On CPU ``interpret=True``
-executes the kernel body in Python; on TPU the same path compiles to
-Mosaic.
+(one kernel dispatch covers every S block).  The kernel compiles to
+Mosaic unless the caller asks for ``interpret=True`` (the CPU test path).
 """
 from __future__ import annotations
 
@@ -53,7 +52,7 @@ def knn_topk(
     tile: int = 128,
     block_r: int = 256,
     block_s: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> TopKState:
     """Merge B_s's candidates into ``state`` (or a fresh k-state) — exact,
     identical scores AND ids to scoring densely then ``topk_update``.

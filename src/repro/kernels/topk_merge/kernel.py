@@ -13,6 +13,9 @@ VPU select/shift over the k lanes (no sort, no concat materialization):
 
 Ties resolve in favour of incumbents (matches jax.lax.top_k stability on a
 [state, candidates] concat).  k ≤ 128 keeps the state in one lane tile.
+Candidate column j is extracted by a one-hot masked max over the chunk's
+lanes (exact: one hit per row), not a lane slice at a traced offset, which
+Mosaic cannot lower.
 """
 from __future__ import annotations
 
@@ -31,14 +34,17 @@ def insert_candidates(state_scores, state_ids, cand_scores, cand_ids):
     Plain arrays in, plain arrays out — callable from any kernel (or traced
     code; it is pure jnp).
     """
-    k = state_scores.shape[1]
+    rows, k = state_scores.shape
     m = cand_scores.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (state_scores.shape[0], k), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, m), 1)
+    id_floor = jnp.iinfo(jnp.int32).min
 
     def insert(j, carry):
         scores, ids = carry
-        cand = cand_scores[:, j][:, None]         # (rows, 1)
-        cid = cand_ids[:, j][:, None]
+        hit = col == j
+        cand = jnp.max(jnp.where(hit, cand_scores, -jnp.inf), axis=1, keepdims=True)
+        cid = jnp.max(jnp.where(hit, cand_ids, id_floor), axis=1, keepdims=True)
         pos = jnp.sum((scores >= cand).astype(jnp.int32), axis=1, keepdims=True)
         sh_s = jnp.roll(scores, 1, axis=1)
         sh_i = jnp.roll(ids, 1, axis=1)

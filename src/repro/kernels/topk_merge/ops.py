@@ -16,7 +16,7 @@ def topk_merge(
     cand_ids: jax.Array,
     block_rows: int = 256,
     chunk_m: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Merge (N, M) candidates into the running (N, k) state. Exact top-k."""
     n, k = state_scores.shape

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.launch.steps import StepOptions, loss_fn
 from repro.optim.adamw import adamw_update
 from repro.optim.compress import psum_int8
@@ -51,9 +50,10 @@ def make_compressed_train_step(cfg, mesh, axis: str = "data",
 
     rep = P()
     batch_spec = {"tokens": P(axis), "labels": P(axis)}
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local_step,
-        mesh,
+        mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
+        check_vma=False,
     ))

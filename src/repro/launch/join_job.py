@@ -24,6 +24,7 @@ import time
 import numpy as np
 
 from repro.configs.paper_knn import JoinConfig
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.sparse.datagen import spectra_like, synthetic_sparse
 
 
@@ -94,6 +95,7 @@ def dryrun_ring(cfg: JoinConfig, multi_pod: bool = False):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nr", type=int, default=2000)
     ap.add_argument("--ns", type=int, default=4000)

@@ -13,20 +13,26 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler, as the shard_map programs and pjit'd steps expect)."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over the real local devices (tests / CPU examples)."""
     n = len(jax.devices())
     assert data * model <= n, f"need {data * model} devices, have {n}"
-    return compat.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_store_mesh(num_shards: int | None = None, replicas: int = 1):
@@ -45,12 +51,12 @@ def make_store_mesh(num_shards: int | None = None, replicas: int = 1):
     if replicas == 1:
         shards = n if num_shards is None else num_shards
         assert 1 <= shards <= n, f"need {shards} devices, have {n}"
-        return compat.make_mesh((shards,), ("shard",))
+        return make_mesh((shards,), ("shard",))
     shards = (n // replicas) if num_shards is None else num_shards
     assert shards >= 1, f"{n} devices cannot host {replicas} replicas"
     assert replicas * shards <= n, (
         f"need {replicas}x{shards} devices, have {n}")
-    return compat.make_mesh((replicas, shards), ("replica", "shard"))
+    return make_mesh((replicas, shards), ("replica", "shard"))
 
 
 def replica_submeshes(mesh, replica_axis: str = "replica") -> list:
@@ -67,6 +73,21 @@ def replica_submeshes(mesh, replica_axis: str = "replica") -> list:
     shard_names = tuple(n for n in names if n != replica_axis)
     devs = np.moveaxis(mesh.devices, ax, 0)
     return [Mesh(devs[r], shard_names) for r in range(devs.shape[0])]
+
+
+def submesh_compiler_options(mesh) -> dict | None:
+    """Compiler options for a program that spans PART of a TPU slice.
+
+    By default the TPU runtime opens every multi-chip program with a launch
+    barrier across all chips of the slice ("Enhanced barrier buffer must
+    include all devices in the slice"); a replica's program runs on only
+    its own chips, and the barrier then halts the device.  Such programs
+    compile without it.  Single-chip and whole-slice programs, and other
+    platforms, keep the defaults (``None``)."""
+    devs = mesh.devices.ravel()
+    if devs[0].platform == "tpu" and 1 < devs.size < len(jax.devices()):
+        return {"xla_tpu_use_enhanced_launch_barrier": False}
+    return None
 
 
 def dp_axes(mesh) -> tuple:

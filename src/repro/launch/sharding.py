@@ -175,10 +175,9 @@ def store_stack_specs(tree, axes) -> Any:
 
 def store_put(tree, mesh: Mesh, axes):
     """Place a store stack pytree on the mesh, leading axis sharded."""
-    from repro import compat
-
     specs = store_stack_specs(tree, axes)
-    return jax.tree.map(lambda x, s: compat.shard_put(x, mesh, s), tree, specs)
+    return jax.tree.map(
+        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs)
 
 
 def store_shard_update(arr, i: int, new_slice) -> "jax.Array":
@@ -213,7 +212,7 @@ def store_shard_update(arr, i: int, new_slice) -> "jax.Array":
                 local = np.asarray(s.data).copy()
                 local[i - lo] = new_slice[0]
             bufs.append(jax.device_put(
-                jax.numpy.asarray(local, dtype=arr.dtype), s.device))
+                np.asarray(local, dtype=arr.dtype), s.device))
         else:
             bufs.append(s.data)
     return jax.make_array_from_single_device_arrays(

@@ -91,7 +91,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import iiib as iiib_mod
 from repro.core import lsh as lsh_mod
 from repro.core.bf import bf_scan_join
@@ -200,6 +199,151 @@ class StoreStats:
     def expose(self) -> str:
         """OpenMetrics-style text exposition of the store counters."""
         return self.registry.expose()
+
+
+def fanout_program(algorithm: str, mesh, axes: Tuple[str, ...], *, rb: int,
+                   k: int, dim: int, s_block: int, tile: int,
+                   approx: bool = False):
+    """The jitted ``shard_map`` program of one R block: shard-local
+    scanned join → on-device tree reduction.  No cross-replica
+    collective — each replica's program spans only its own devices
+    (``mesh`` is one replica's sub-mesh), which is what lets a dead
+    replica be routed around.
+
+    ``approx`` compiles a variant whose locals prepend the band-lookup
+    pass: the replicated R band keys membership-test each shard's
+    ``lshk`` stack (``lsh.band_hits``) and the candidate mask ANDs
+    into the shard's valid mask — still ONE dispatch per R block; the
+    live-candidate counts ride back via ``all_gather``.  Arguments follow
+    :meth:`ShardedKNNStore._fanout_args`."""
+    from repro.launch.mesh import submesh_compiler_options
+
+    alg, sb = algorithm, s_block
+    nsh = int(np.prod([mesh.shape[a] for a in axes]))
+    rep = P()
+    shard = P(axes)
+    state_spec = TopKState(scores=rep, ids=rep)
+
+    if alg == "bf" and not approx:
+        def local(bi, bv, bn, s_idx, s_val, s_nnz, s_ids, s_valid):
+            br = SparseBatch(indices=bi, values=bv, nnz=bn, dim=dim)
+            state = init_topk(rb, k)
+            state = bf_scan_join(
+                state, br, s_idx[0], s_val[0], s_nnz[0], s_ids[0], s_valid[0],
+                dim=dim,
+            )
+            return tree_reduce_topk(state, axes, nsh)
+
+        fn = jax.shard_map(
+            local, mesh=mesh, check_vma=False,
+            in_specs=(rep, rep, rep) + (shard,) * 5,
+            out_specs=state_spec,
+        )
+    elif alg == "bf":
+        def local(bi, bv, bn, rk, rr,
+                  s_idx, s_val, s_nnz, s_ids, s_valid, s_lshk):
+            br = SparseBatch(indices=bi, values=bv, nnz=bn, dim=dim)
+            vm = jnp.logical_and(
+                s_valid[0], lsh_mod.band_hits(rk, rr, s_lshk[0]))
+            state = init_topk(rb, k)
+            state = bf_scan_join(
+                state, br, s_idx[0], s_val[0], s_nnz[0], s_ids[0], vm,
+                dim=dim,
+            )
+            return (
+                tree_reduce_topk(state, axes, nsh),
+                jax.lax.all_gather(jnp.sum(vm), axes),
+            )
+
+        fn = jax.shard_map(
+            local, mesh=mesh, check_vma=False,
+            in_specs=(rep,) * 5 + (shard,) * 6,
+            out_specs=(state_spec, rep),
+        )
+    elif alg == "iib" and not approx:
+        def local(r_tiles, tiles, s_rows, s_vals, s_counts, s_ids, s_valid):
+            state = init_topk(rb, k)
+            state = iib_scan_join(
+                state, r_tiles, tiles,
+                s_rows[0], s_vals[0], s_counts[0], s_ids[0], s_valid[0],
+                tile=tile, num_s=sb,
+            )
+            return tree_reduce_topk(state, axes, nsh)
+
+        fn = jax.shard_map(
+            local, mesh=mesh, check_vma=False,
+            in_specs=(rep, rep) + (shard,) * 5,
+            out_specs=state_spec,
+        )
+    elif alg == "iib":
+        def local(r_tiles, tiles, rk, rr,
+                  s_rows, s_vals, s_counts, s_ids, s_valid, s_lshk):
+            vm = jnp.logical_and(
+                s_valid[0], lsh_mod.band_hits(rk, rr, s_lshk[0]))
+            state = init_topk(rb, k)
+            state = iib_scan_join(
+                state, r_tiles, tiles,
+                s_rows[0], s_vals[0], s_counts[0], s_ids[0], vm,
+                tile=tile, num_s=sb,
+            )
+            return (
+                tree_reduce_topk(state, axes, nsh),
+                jax.lax.all_gather(jnp.sum(vm), axes),
+            )
+
+        fn = jax.shard_map(
+            local, mesh=mesh, check_vma=False,
+            in_specs=(rep,) * 4 + (shard,) * 6,
+            out_specs=(state_spec, rep),
+        )
+    elif not approx:
+        def local(r_tiles, mwt, tiles, rv,
+                  s_rows, s_vals, s_counts, s_mass, s_ids, s_valid):
+            state = init_topk(rb, k)
+            # each shard carries its OWN MinPruneScore — work-only
+            # divergence from the sequential scan (see module docstring)
+            state, thr, _, kept = iiib_scan_join(
+                state, jnp.float32(-jnp.inf), r_tiles, mwt, tiles,
+                s_rows[0], s_vals[0], s_counts[0], s_mass[0], s_ids[0],
+                s_valid[0], rv, tile=tile, num_s=sb,
+            )
+            red = tree_reduce_topk(state, axes, nsh)
+            return (
+                red,
+                jax.lax.all_gather(jnp.sum(kept), axes),
+                jax.lax.all_gather(thr, axes),
+            )
+
+        fn = jax.shard_map(
+            local, mesh=mesh, check_vma=False,
+            in_specs=(rep, rep, rep, rep) + (shard,) * 6,
+            out_specs=(state_spec, rep, rep),
+        )
+    else:
+        def local(r_tiles, mwt, tiles, rv, rk, rr,
+                  s_rows, s_vals, s_counts, s_mass, s_ids, s_valid, s_lshk):
+            vm = jnp.logical_and(
+                s_valid[0], lsh_mod.band_hits(rk, rr, s_lshk[0]))
+            state = init_topk(rb, k)
+            state, thr, _, kept = iiib_scan_join(
+                state, jnp.float32(-jnp.inf), r_tiles, mwt, tiles,
+                s_rows[0], s_vals[0], s_counts[0], s_mass[0], s_ids[0],
+                vm, rv, tile=tile, num_s=sb,
+            )
+            red = tree_reduce_topk(state, axes, nsh)
+            return (
+                red,
+                jax.lax.all_gather(jnp.sum(kept), axes),
+                jax.lax.all_gather(thr, axes),
+                jax.lax.all_gather(jnp.sum(vm), axes),
+            )
+
+        fn = jax.shard_map(
+            local, mesh=mesh, check_vma=False,
+            in_specs=(rep,) * 6 + (shard,) * 7,
+            out_specs=(state_spec, rep, rep, rep),
+        )
+    return jax.jit(fn, compiler_options=submesh_compiler_options(mesh))
 
 
 def _np_sparse_slice(idx, val, nnz, lo: int, hi: int, dim: int) -> SparseBatch:
@@ -652,11 +796,11 @@ class ShardedKNNStore:
     def _place_replica_full(self, r: int):
         from repro.launch.sharding import store_put
 
-        tree = {
-            k: jnp.asarray(v)
-            for k, v in self._stacked_host.items() if k != "valid"
-        }
-        tree["valid"] = jnp.asarray(self._replica_valid(r))
+        # host arrays straight into the sharded put: each device receives
+        # only its own shard's slice (a device array made first would land
+        # whole on the default device)
+        tree = {k: v for k, v in self._stacked_host.items() if k != "valid"}
+        tree["valid"] = self._replica_valid(r)
         self._stacks[r] = store_put(tree, self._replica_meshes[r], self._axes)
         self.stats.placed_shards += self.n_shards
         self.stats.placed_bytes += sum(
@@ -681,8 +825,7 @@ class ShardedKNNStore:
         from repro.launch.sharding import store_put
 
         new_valid = store_put(
-            jnp.asarray(self._replica_valid(r)),
-            self._replica_meshes[r], self._axes,
+            self._replica_valid(r), self._replica_meshes[r], self._axes,
         )
         self._stacks[r] = dict(self._stacks[r], valid=new_valid)
 
@@ -707,148 +850,16 @@ class ShardedKNNStore:
     # -- fan-out query -------------------------------------------------------
 
     def _query_fn(self, rb: int, replica: int = 0, approx: bool = False):
-        """The jitted shard_map program of one R block (cached per R-block
-        size AND per replica sub-mesh AND per accuracy): shard-local
-        scanned join → on-device tree reduction.  No cross-replica
-        collective — each replica's program spans only its own devices,
-        which is what lets a dead replica be routed around.
-
-        ``approx`` compiles a variant whose locals prepend the band-lookup
-        pass: the replicated R band keys membership-test each shard's
-        ``lshk`` stack (``lsh.band_hits``) and the candidate mask ANDs
-        into the shard's valid mask — still ONE dispatch per R block; the
-        live-candidate counts ride back via ``all_gather``.  Exact-mode
-        programs are keyed separately and byte-identical to before."""
+        """The jitted fan-out program of one R block, cached per R-block
+        size AND per replica sub-mesh AND per accuracy (see
+        :func:`fanout_program`)."""
         key = (rb, replica, approx)
-        if key in self._query_fns:
-            return self._query_fns[key]
-        mesh, axes, nsh = self._replica_meshes[replica], self._axes, self.n_shards
-        k, dim, sb, tile = self.spec.k, self.dim, self.s_block, self.tile
-        alg = self.algorithm
-        rep = P()
-        shard = P(axes)
-        state_spec = TopKState(scores=rep, ids=rep)
-
-        if alg == "bf" and not approx:
-            def local(bi, bv, bn, s_idx, s_val, s_nnz, s_ids, s_valid):
-                br = SparseBatch(indices=bi, values=bv, nnz=bn, dim=dim)
-                state = init_topk(rb, k)
-                state = bf_scan_join(
-                    state, br, s_idx[0], s_val[0], s_nnz[0], s_ids[0], s_valid[0],
-                    dim=dim,
-                )
-                return tree_reduce_topk(state, axes, nsh)
-
-            fn = compat.shard_map(
-                local, mesh,
-                in_specs=(rep, rep, rep) + (shard,) * 5,
-                out_specs=state_spec,
+        if key not in self._query_fns:
+            self._query_fns[key] = fanout_program(
+                self.algorithm, self._replica_meshes[replica], self._axes,
+                rb=rb, k=self.spec.k, dim=self.dim, s_block=self.s_block,
+                tile=self.tile, approx=approx,
             )
-        elif alg == "bf":
-            def local(bi, bv, bn, rk, rr,
-                      s_idx, s_val, s_nnz, s_ids, s_valid, s_lshk):
-                br = SparseBatch(indices=bi, values=bv, nnz=bn, dim=dim)
-                vm = jnp.logical_and(
-                    s_valid[0], lsh_mod.band_hits(rk, rr, s_lshk[0]))
-                state = init_topk(rb, k)
-                state = bf_scan_join(
-                    state, br, s_idx[0], s_val[0], s_nnz[0], s_ids[0], vm,
-                    dim=dim,
-                )
-                return (
-                    tree_reduce_topk(state, axes, nsh),
-                    jax.lax.all_gather(jnp.sum(vm), axes),
-                )
-
-            fn = compat.shard_map(
-                local, mesh,
-                in_specs=(rep,) * 5 + (shard,) * 6,
-                out_specs=(state_spec, rep),
-            )
-        elif alg == "iib" and not approx:
-            def local(r_tiles, tiles, s_rows, s_vals, s_counts, s_ids, s_valid):
-                state = init_topk(rb, k)
-                state = iib_scan_join(
-                    state, r_tiles, tiles,
-                    s_rows[0], s_vals[0], s_counts[0], s_ids[0], s_valid[0],
-                    tile=tile, num_s=sb,
-                )
-                return tree_reduce_topk(state, axes, nsh)
-
-            fn = compat.shard_map(
-                local, mesh,
-                in_specs=(rep, rep) + (shard,) * 5,
-                out_specs=state_spec,
-            )
-        elif alg == "iib":
-            def local(r_tiles, tiles, rk, rr,
-                      s_rows, s_vals, s_counts, s_ids, s_valid, s_lshk):
-                vm = jnp.logical_and(
-                    s_valid[0], lsh_mod.band_hits(rk, rr, s_lshk[0]))
-                state = init_topk(rb, k)
-                state = iib_scan_join(
-                    state, r_tiles, tiles,
-                    s_rows[0], s_vals[0], s_counts[0], s_ids[0], vm,
-                    tile=tile, num_s=sb,
-                )
-                return (
-                    tree_reduce_topk(state, axes, nsh),
-                    jax.lax.all_gather(jnp.sum(vm), axes),
-                )
-
-            fn = compat.shard_map(
-                local, mesh,
-                in_specs=(rep,) * 4 + (shard,) * 6,
-                out_specs=(state_spec, rep),
-            )
-        elif not approx:
-            def local(r_tiles, mwt, tiles, rv,
-                      s_rows, s_vals, s_counts, s_mass, s_ids, s_valid):
-                state = init_topk(rb, k)
-                # each shard carries its OWN MinPruneScore — work-only
-                # divergence from the sequential scan (see module docstring)
-                state, thr, _, kept = iiib_scan_join(
-                    state, jnp.float32(-jnp.inf), r_tiles, mwt, tiles,
-                    s_rows[0], s_vals[0], s_counts[0], s_mass[0], s_ids[0],
-                    s_valid[0], rv, tile=tile, num_s=sb,
-                )
-                red = tree_reduce_topk(state, axes, nsh)
-                return (
-                    red,
-                    jax.lax.all_gather(jnp.sum(kept), axes),
-                    jax.lax.all_gather(thr, axes),
-                )
-
-            fn = compat.shard_map(
-                local, mesh,
-                in_specs=(rep, rep, rep, rep) + (shard,) * 6,
-                out_specs=(state_spec, rep, rep),
-            )
-        else:
-            def local(r_tiles, mwt, tiles, rv, rk, rr,
-                      s_rows, s_vals, s_counts, s_mass, s_ids, s_valid, s_lshk):
-                vm = jnp.logical_and(
-                    s_valid[0], lsh_mod.band_hits(rk, rr, s_lshk[0]))
-                state = init_topk(rb, k)
-                state, thr, _, kept = iiib_scan_join(
-                    state, jnp.float32(-jnp.inf), r_tiles, mwt, tiles,
-                    s_rows[0], s_vals[0], s_counts[0], s_mass[0], s_ids[0],
-                    vm, rv, tile=tile, num_s=sb,
-                )
-                red = tree_reduce_topk(state, axes, nsh)
-                return (
-                    red,
-                    jax.lax.all_gather(jnp.sum(kept), axes),
-                    jax.lax.all_gather(thr, axes),
-                    jax.lax.all_gather(jnp.sum(vm), axes),
-                )
-
-            fn = compat.shard_map(
-                local, mesh,
-                in_specs=(rep,) * 6 + (shard,) * 7,
-                out_specs=(state_spec, rep, rep, rep),
-            )
-        self._query_fns[key] = jax.jit(fn)
         return self._query_fns[key]
 
     def _fanout_args(self, br, prep, r_valid, st, approx: bool,

@@ -16,8 +16,8 @@ from repro.sparse.datagen import synthetic_sparse
 from repro.sparse.format import densify
 from repro.core.ring import ring_knn_join, pad_to_ring
 from repro.core.reference import oracle_knn
-from repro import compat
-mesh = compat.make_mesh((4, 2), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ('data', 'model'))
 R = synthetic_sparse(60, dim=512, nnz_mean=20, seed=0)
 S = synthetic_sparse(90, dim=512, nnz_mean=20, seed=1)
 Rp, nr = pad_to_ring(R, 4); Sp, ns = pad_to_ring(S, 4)
@@ -85,8 +85,8 @@ from repro.launch.sharding import (batch_shardings, param_shardings,
                                    opt_shardings, cache_shardings)
 from repro.launch.steps import (StepOptions, abstract_train_state,
                                 make_train_step, make_decode_step)
-from repro import compat
-mesh = compat.make_mesh((4, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 4), ('data', 'model'))
 cfg = get_config('qwen3-0.6b').reduced()
 params_abs, opt_abs = abstract_train_state(cfg)
 p_sh = param_shardings(params_abs, mesh)

@@ -170,6 +170,25 @@ def test_fused_kernel_engine_matches_streaming(small_rs):
     assert stats.device_dispatches == 2                       # == r_blocks
 
 
+def test_fused_kernel_interprets_only_on_cpu(small_rs, monkeypatch):
+    """The kernel path lowers the call query() makes: interpreted on the CPU
+    backend (no Mosaic custom call), refused on a backend with no kernel
+    path; an index without the kernel path has nothing to lower."""
+    import jax
+
+    R, S = small_rs
+    spec = JoinSpec(k=5, algorithm="iib", r_block=24, s_block=32, use_kernel=True)
+    index = SparseKNNIndex.build(S, spec)
+    assert "tpu_custom_call" not in index.lowered_kernel(R).as_text()
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="use_kernel needs a TPU"):
+        index.query(R)
+    monkeypatch.undo()
+    plain = SparseKNNIndex.build(S, JoinSpec(k=5, algorithm="iib", r_block=24, s_block=32))
+    with pytest.raises(ValueError, match="no fused-kernel query path"):
+        plain.lowered_kernel(R)
+
+
 def test_warm_start_seed_varies_sample(small_rs):
     """JoinSpec.seed varies the warm-start sample across a stream; every
     seed stays exact."""

@@ -31,7 +31,7 @@ from repro.sparse.format import densify, tile_occupancy
 def test_knn_score_shapes(nr, ns, dim, tile, br, bs):
     R = synthetic_sparse(nr, dim=dim, nnz_mean=15, nnz_std=4, seed=nr + ns)
     S = synthetic_sparse(ns, dim=dim, nnz_mean=15, nnz_std=4, seed=nr * ns)
-    out = np.asarray(knn_score(R, S, tile=tile, block_r=br, block_s=bs))
+    out = np.asarray(knn_score(R, S, tile=tile, block_r=br, block_s=bs, interpret=True))
     truth = np.asarray(densify(R)) @ np.asarray(densify(S)).T
     np.testing.assert_allclose(out, truth, atol=1e-4)
 
@@ -99,7 +99,7 @@ def test_topk_merge_shapes(n, k, m):
     st = init_topk(n, k)
     cand = rng.standard_normal((n, m)).astype(np.float32)
     ids = np.tile(np.arange(m, dtype=np.int32), (n, 1))
-    out_s, out_i = topk_merge(st.scores, st.ids, jnp.asarray(cand), jnp.asarray(ids))
+    out_s, out_i = topk_merge(st.scores, st.ids, jnp.asarray(cand), jnp.asarray(ids), interpret=True)
     ref = topk_update(st, jnp.asarray(cand), jnp.asarray(np.arange(m, dtype=np.int32)))
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(ref.scores), atol=1e-6)
 
@@ -111,11 +111,11 @@ def test_topk_merge_streaming_equals_batch():
     cand = rng.standard_normal((n, m)).astype(np.float32)
     ids = np.tile(np.arange(m, dtype=np.int32), (n, 1))
     st = init_topk(n, k)
-    s1, i1 = topk_merge(st.scores, st.ids, jnp.asarray(cand), jnp.asarray(ids))
+    s1, i1 = topk_merge(st.scores, st.ids, jnp.asarray(cand), jnp.asarray(ids), interpret=True)
     s2, i2 = st.scores, st.ids
     for lo in range(0, m, 64):
         s2, i2 = topk_merge(s2, i2, jnp.asarray(cand[:, lo:lo + 64]),
-                            jnp.asarray(ids[:, lo:lo + 64]))
+                            jnp.asarray(ids[:, lo:lo + 64]), interpret=True)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-6)
 
 
@@ -125,7 +125,7 @@ def test_topk_merge_with_ties():
     st = init_topk(n, k)
     cand = np.ones((n, 6), np.float32)
     ids = np.tile(np.arange(6, dtype=np.int32), (n, 1))
-    s, i = topk_merge(st.scores, st.ids, jnp.asarray(cand), jnp.asarray(ids))
+    s, i = topk_merge(st.scores, st.ids, jnp.asarray(cand), jnp.asarray(ids), interpret=True)
     assert (np.asarray(s) == 1.0).all()
     # ids are a subset of the candidates, no repeats per row
     for row in np.asarray(i):

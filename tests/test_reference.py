@@ -1,9 +1,9 @@
 """Paper-faithful host reference implementations (Algorithms 1-4)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
-from repro.core.reference import HostCSR, oracle_knn, reference_join
+from repro.core.reference import HostCSR, oracle_knn, reference_join, topk_agreement
 from repro.sparse.datagen import synthetic_sparse
 from repro.sparse.format import densify
 
@@ -86,3 +86,24 @@ def test_threshold_tightens_across_blocks(small_rs):
         mps.append(state.min_prune_score())
     assert mps[-1] > -np.inf
     assert all(b >= a for a, b in zip(mps, mps[1:])), mps
+
+
+def test_topk_agreement_tolerates_only_ties():
+    """Scores within tol pass; ids are held to the reference only at slots
+    more than tol from both neighbours (the k-th slot's lower neighbour
+    comes from a one-deeper reference, or the slot counts as tied)."""
+    ref_s = np.array([[5.0, 4.0, 4.0 - 1e-6, 2.0, 1.0, 0.5],
+                      [3.0, 2.0, 1.0, -np.inf, -np.inf, -np.inf]])
+    ref_i = np.array([[0, 1, 2, 3, 4, 5], [7, 8, 9, -1, -1, -1]])
+    got_s, got_i = ref_s[:, :5].copy(), ref_i[:, :5].copy()
+    got_i[0, [1, 2]] = [2, 1]                 # a swap inside a tie
+    got_s[0, 0] += 1e-5                       # rounding within tol
+    a = topk_agreement(ref_s, ref_i, got_s, got_i, tol=1e-4)
+    assert a == {"max_score_err": pytest.approx(1e-5), "ids_checked": 6,
+                 "id_mismatches": 0}
+    assert topk_agreement(ref_s[:, :5], ref_i[:, :5], got_s, got_i,
+                          tol=1e-4)["ids_checked"] == 5
+    got_i[0, 3] = 9                           # an untied slot differs
+    assert topk_agreement(ref_s, ref_i, got_s, got_i, tol=1e-4)["id_mismatches"] == 1
+    got_s[1, 3] = 0.1                         # an empty slot filled
+    assert topk_agreement(ref_s, ref_i, got_s, got_i, tol=1e-4)["max_score_err"] == np.inf

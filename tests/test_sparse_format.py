@@ -1,7 +1,7 @@
 """SparseBatch format + dim/tile statistics."""
 import numpy as np
 import jax.numpy as jnp
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.sparse.format import (
     SparseBatch,

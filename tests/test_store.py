@@ -221,7 +221,7 @@ def test_store_refreeze_matches_and_multi_axis_mesh():
     ring join's configuration)."""
     out = run_with_devices("""
 import numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.sparse.datagen import synthetic_sparse
 from repro.core.engine import JoinSpec
 from repro.store import ShardedKNNStore
@@ -229,7 +229,7 @@ from repro.store import ShardedKNNStore
 R = synthetic_sparse(20, dim=512, nnz_mean=18, seed=0)
 S = synthetic_sparse(90, dim=512, nnz_mean=18, seed=1)
 spec = JoinSpec(k=5, algorithm='iiib', s_block=16, r_block=20)
-mesh = compat.make_mesh((2, 2), ('data', 'model'))
+mesh = make_mesh((2, 2), ('data', 'model'))
 store = ShardedKNNStore.build(S, spec, mesh=mesh, axes=('data',))
 assert store.n_shards == 2
 r1 = store.query(R)
@@ -253,11 +253,11 @@ def test_traced_ring_join_lowers_via_legacy_ring():
     back to the fully-traceable ppermute ring for abstract inputs."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.ring import ring_knn_join
 from repro.sparse.format import SparseBatch
 
-mesh = compat.make_mesh((4,), ('data',))
+mesh = make_mesh((4,), ('data',))
 nr, ns, f, dim = 32, 64, 16, 512
 
 def job(Ri, Rv, Rn, Si, Sv, Sn):

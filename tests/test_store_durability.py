@@ -40,6 +40,18 @@ def assert_parity(ref, got, what):
         f"{what}: ids diverged"
     assert (np.asarray(ref.scores) == np.asarray(got.scores)).all(), \
         f"{what}: scores diverged"
+
+# The scheduler pads R to its r_block (32 rows); the direct query runs one
+# 24-row block.  The two compiled programs may round an f32 sum differently
+# in the last ulp (scores here are < 8, one ulp < 1e-6), so served results
+# match the direct query within 1e-5, and ids wherever scores are not tied.
+SERVE_TOL = 1e-5
+
+def assert_close(ref, got, what):
+    from repro.core.reference import topk_agreement
+    a = topk_agreement(ref.scores, ref.ids, got.scores, got.ids, SERVE_TOL)
+    assert a["max_score_err"] <= SERVE_TOL and a["id_mismatches"] == 0, \
+        f"{what}: {a}"
 """
 
 
@@ -155,9 +167,9 @@ async def main():
         assert store.lost_shards == (), "recovery never completed"
         res2 = await sched.submit(R, k=5)
         assert not res2.degraded
-        assert_parity(direct, type("J", (), {"ids": res2[0],
-                                             "scores": res2[1]}),
-                      "post-recovery")
+        assert_close(direct, type("J", (), {"ids": res2[0],
+                                            "scores": res2[1]}),
+                     "post-recovery")
         m = sched.metrics
     assert m.failed == 0
     assert m.shard_losses >= 1 and m.degraded >= 1 and m.recoveries == 1
@@ -194,9 +206,9 @@ async def main():
             [FaultSpec("shard_error", shard=2, at_dispatch=0)])
         res = await sched.submit(R, k=5)      # resolves only when FULL
         assert res.missing_shards == ()
-        assert_parity(direct, type("J", (), {"ids": res[0],
-                                             "scores": res[1]}),
-                      "queued-behind-recovery")
+        assert_close(direct, type("J", (), {"ids": res[0],
+                                            "scores": res[1]}),
+                     "queued-behind-recovery")
         m = sched.metrics
     assert m.failed == 0 and m.degraded == 0
     assert m.shard_losses >= 1 and m.recoveries == 1

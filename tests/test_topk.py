@@ -1,9 +1,15 @@
 """Streaming top-k state properties (hypothesis)."""
 import numpy as np
 import jax.numpy as jnp
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
-from repro.core.topk import init_topk, min_prune_score, prune_scores, topk_update
+from repro.core.topk import (
+    init_topk,
+    merge_topk_states,
+    min_prune_score,
+    prune_scores,
+    topk_update,
+)
 
 
 @settings(max_examples=30, deadline=None)
@@ -42,3 +48,26 @@ def test_neg_inf_initialization():
     state = init_topk(4, 3)
     assert np.isneginf(np.asarray(state.scores)).all()
     assert (np.asarray(state.ids) == -1).all()
+
+
+@settings(max_examples=25, deadline=None)
+@given(st.integers(1, 6), st.integers(1, 9), st.integers(0, 10_000))
+def test_merge_topk_states_matches_topk_update(n, k, seed):
+    """The shared insertion body (``insert_candidates``, whose column
+    extraction is a one-hot masked max) merges two states exactly as a
+    concat + lax.top_k does: same scores, same ids, ties to the first
+    state, empty (-inf) slots included."""
+    rng = np.random.default_rng(seed)
+
+    def state(offset):
+        m = int(rng.integers(0, 2 * k + 1))
+        sc = rng.integers(0, 4, (n, m)).astype(np.float32)   # many ties
+        sc[rng.random((n, m)) < 0.25] = -np.inf
+        ids = offset + np.arange(m, dtype=np.int32)
+        return topk_update(init_topk(n, k), jnp.asarray(sc), jnp.asarray(ids))
+
+    a, b = state(0), state(1000)
+    got = merge_topk_states(a, b)
+    want = topk_update(a, b.scores, b.ids)
+    np.testing.assert_array_equal(np.asarray(got.scores), np.asarray(want.scores))
+    np.testing.assert_array_equal(np.asarray(got.ids), np.asarray(want.ids))
