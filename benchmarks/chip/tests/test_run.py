@@ -1,0 +1,96 @@
+"""The harness end to end on the CPU at a tiny size, with the chip check
+skipped, and with the timed path broken underneath to see ``correct``
+come out false."""
+import dataclasses
+import json
+import os
+import pathlib
+import subprocess
+import sys
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import layout
+import run
+
+ROOT = pathlib.Path(__file__).resolve().parents[3]
+SEED = 2**31 + 4242
+def _tiny(cell, config):
+    """The cell's loop over ``configs/<config>.json`` at a tiny size."""
+    bench = layout.benchmark()
+    w = layout.cell(bench, cell)
+    cfg = json.loads((layout.HERE / "configs" / f"{config}.json").read_text())
+    cfg.update(n_s=500, n_r=600)
+    cfg["dim"] = 2000 if cfg["data"]["generator"] == "synthetic" else 4000
+    traffic = dict(layout.traffic(w["traffic"]), rows_per_call=256)
+    return bench, cfg, traffic
+
+
+def _run(cell, config, fault=None, seconds=1.5):
+    bench, cfg, traffic = _tiny(cell, config)
+    return run.run_cell(bench, cell, SEED, seconds, False, require_chip=False,
+                        cfg=cfg, traffic=traffic, fault=fault)
+
+
+def test_without_an_accelerator_it_exits_nonzero_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "benchmarks/chip/run.py", "--workload", "synth50k.join",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 2
+    assert "{" not in p.stdout
+    assert "no chip" in p.stderr
+
+
+# every configuration on file, the deferred spectra52k too (PERF.md §7)
+CASES = [("synth50k.join", "synth50k"), ("synth50k.join", "spectra52k")]
+
+
+@pytest.mark.parametrize("cell,config", CASES)
+def test_a_tiny_run_is_correct_and_names_its_device(cell, config):
+    res = _run(cell, config)
+    assert res["correct"], res["check"]
+    assert res["device"] == {"platform": "cpu", "kind": "cpu", "count": 1,
+                             "memory_peak_bytes": 0}
+    want = {m["name"] for m in layout.metrics_of(layout.benchmark(), "end_to_end", cell)}
+    assert set(res["metrics"]) == want
+    assert list(res)[-1] == "check"
+    assert res["info"]["compiles_in_window"] == 0
+
+
+def _altered(store):
+    """Every eighth row's best neighbour replaced where it is produced."""
+    query = store.query
+
+    def altered(R, **kw):
+        res = query(R, **kw)
+        ids = np.asarray(res.ids).copy()
+        ids[::8, 0] = (ids[::8, 0] + 1) % store.num_vectors
+        return dataclasses.replace(res, ids=jnp.asarray(ids))
+
+    store.query = altered
+
+
+def _half_left_out(store):
+    """The second half of every block's live rows left without an answer."""
+    query = store.query
+
+    def halved(R, **kw):
+        res = query(R, **kw)
+        ids, scores = np.asarray(res.ids).copy(), np.asarray(res.scores).copy()
+        live = np.flatnonzero(np.asarray(R.nnz) > 0)
+        gone = live[len(live) // 2:]
+        ids[gone], scores[gone] = -1, -np.inf
+        return dataclasses.replace(res, ids=jnp.asarray(ids), scores=jnp.asarray(scores))
+
+    store.query = halved
+
+
+@pytest.mark.parametrize("fault", [_altered, _half_left_out], ids=["altered", "half"])
+@pytest.mark.parametrize("cell,config", CASES)
+def test_a_broken_timed_path_is_not_correct(cell, config, fault):
+    res = _run(cell, config, fault=fault)
+    assert not res["correct"], res["check"]
