@@ -55,7 +55,7 @@ import time
 import numpy as np
 
 from repro.core import JoinSpec
-from repro.obs import FlightRecorder, fanout_report, set_recorder
+from repro.obs import FlightRecorder, set_recorder
 from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import KNNScheduler, QueueFull, ServeConfig
 from repro.sparse.datagen import synthetic_sparse
@@ -255,13 +255,6 @@ def run(n_requests: int, rate: float, n_store: int, dim: int, nnz: int,
         "shards": store.n_shards,
         "device_count": jax.device_count(),
     }
-
-    # predicted-vs-measured FLOPs/bytes of the one fan-out program the
-    # whole run dispatched (hlo_analysis over the lowered module)
-    try:
-        record["hlo"] = fanout_report(store, slice_rows(pool, 0, r_block))
-    except Exception as e:   # cost-analysis coverage varies by backend
-        record["hlo"] = {"error": str(e)}
 
     # bit-parity of de-interleaved results vs direct per-request queries:
     # re-serve a sample through a fresh scheduler and compare

@@ -37,10 +37,11 @@ def bf_block_scores(
         start = c * dim_chunk
         rt = densify_tile(r_block, start, dim_chunk)  # (Nr, chunk)
         st = densify_tile(s_block, start, dim_chunk)  # (Ns, chunk)
-        return acc + jax.lax.dot_general(
-            rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        with jax.named_scope("knn.matmul"):
+            return acc + jax.lax.dot_general(
+                rt, st, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
 
     acc = jnp.zeros((r_block.num_vectors, s_block.num_vectors), dtype=jnp.float32)
     return jax.lax.fori_loop(0, n_chunks, body, acc)

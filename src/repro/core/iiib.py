@@ -147,20 +147,22 @@ def _masked_block(
     score is -inf forever — they never pass ``a_kept > 0``) from pinning
     the threshold at -inf; sound because the threshold only has to
     lower-bound the pruneScore of rows that can actually offer."""
-    contrib = maxw_tile[None, :] * tilemass            # (|Bs|, T)
-    cum = jnp.cumsum(contrib, axis=1)                  # inclusive prefix bound
-    keep = cum > thr                                   # entry (s, t) stays indexed
-    pref_ub = jnp.sum(jnp.where(keep, 0.0, contrib), axis=1)
+    with jax.named_scope("knn.bound"):
+        contrib = maxw_tile[None, :] * tilemass        # (|Bs|, T)
+        cum = jnp.cumsum(contrib, axis=1)              # inclusive prefix bound
+        keep = cum > thr                               # entry (s, t) stays indexed
+        pref_ub = jnp.sum(jnp.where(keep, 0.0, contrib), axis=1)
     a_kept, a_full = masked_tile_scores(r_tiles, index, active_tiles, keep)
     prune = prune_scores(state)
     # Theorem 1 (shared kept feature) + the A + prefUB > pruneScore bound;
     # offered value is the EXACT dot (a_full) — completion without rescue
-    offer = (
-        (a_kept > 0.0)
-        & (a_kept + pref_ub[None, :] > prune[:, None])
-        & s_valid[None, :]
-    )
-    scores = jnp.where(offer, a_full, NEG_INF)
+    with jax.named_scope("knn.bound"):
+        offer = (
+            (a_kept > 0.0)
+            & (a_kept + pref_ub[None, :] > prune[:, None])
+            & s_valid[None, :]
+        )
+        scores = jnp.where(offer, a_full, NEG_INF)
     ids = block_ids(s_offset, index.num_s)
     state = topk_update(state, scores, ids)
     kept_entries = jnp.sum(((tilemass > 0.0) & keep).astype(jnp.int32))

@@ -257,11 +257,13 @@ def tile_scores(
     def body(acc, t):
         rt = r_pad[t]                       # (|Br|, tile)
         v = index.vals[t]                   # (M, tile)
-        p = jax.lax.dot_general(
-            rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )                                   # (|Br|, M)
-        acc = acc.at[:, index.rows[t]].add(p)
+        with jax.named_scope("knn.matmul"):
+            p = jax.lax.dot_general(
+                rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )                               # (|Br|, M)
+        with jax.named_scope("knn.scatter"):
+            acc = acc.at[:, index.rows[t]].add(p)
         return acc, None
 
     acc = jnp.zeros((n_r, index.num_s + 1), jnp.float32)
@@ -299,21 +301,25 @@ def masked_tile_scores(
         [r_dense_tiles, jnp.zeros((1,) + r_dense_tiles.shape[1:], r_dense_tiles.dtype)], axis=0
     )
     # sentinel row (id num_s) and sentinel tile column: never kept
-    kp = jnp.zeros((index.num_s + 1, t_total + 1), bool)
-    kp = kp.at[: index.num_s, :t_total].set(keep)
+    with jax.named_scope("knn.bound"):
+        kp = jnp.zeros((index.num_s + 1, t_total + 1), bool)
+        kp = kp.at[: index.num_s, :t_total].set(keep)
 
     def body(accs, t):
         acc_kept, acc_full = accs
         rt = r_pad[t]                       # (|Br|, tile)
         v = index.vals[t]                   # (M, tile)
-        p = jax.lax.dot_general(
-            rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )                                   # (|Br|, M)
+        with jax.named_scope("knn.matmul"):
+            p = jax.lax.dot_general(
+                rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )                               # (|Br|, M)
         rows_t = index.rows[t]
-        keep_t = kp[rows_t, jnp.minimum(t, t_total)]
-        acc_full = acc_full.at[:, rows_t].add(p)
-        acc_kept = acc_kept.at[:, rows_t].add(jnp.where(keep_t[None, :], p, 0.0))
+        with jax.named_scope("knn.bound"):
+            keep_t = kp[rows_t, jnp.minimum(t, t_total)]
+        with jax.named_scope("knn.scatter"):
+            acc_full = acc_full.at[:, rows_t].add(p)
+            acc_kept = acc_kept.at[:, rows_t].add(jnp.where(keep_t[None, :], p, 0.0))
         return (acc_kept, acc_full), None
 
     acc0 = jnp.zeros((n_r, index.num_s + 1), jnp.float32)

@@ -52,12 +52,13 @@ def topk_update(state: TopKState, new_scores: jax.Array, new_ids: jax.Array) -> 
     (N, M).  Invalid candidates must carry score −inf.
     """
     n, m = new_scores.shape
-    if new_ids.ndim == 1:
-        new_ids = jnp.broadcast_to(new_ids[None, :], (n, m))
-    all_scores = jnp.concatenate([state.scores, new_scores.astype(jnp.float32)], axis=1)
-    all_ids = jnp.concatenate([state.ids, new_ids.astype(jnp.int32)], axis=1)
-    top_scores, top_pos = jax.lax.top_k(all_scores, state.k)
-    top_ids = jnp.take_along_axis(all_ids, top_pos, axis=1)
+    with jax.named_scope("knn.topk"):
+        if new_ids.ndim == 1:
+            new_ids = jnp.broadcast_to(new_ids[None, :], (n, m))
+        all_scores = jnp.concatenate([state.scores, new_scores.astype(jnp.float32)], axis=1)
+        all_ids = jnp.concatenate([state.ids, new_ids.astype(jnp.int32)], axis=1)
+        top_scores, top_pos = jax.lax.top_k(all_scores, state.k)
+        top_ids = jnp.take_along_axis(all_ids, top_pos, axis=1)
     return TopKState(scores=top_scores, ids=top_ids)
 
 
@@ -83,7 +84,8 @@ def merge_topk_states(a: TopKState, b: TopKState) -> TopKState:
     """
     from repro.kernels.topk_merge.kernel import insert_candidates
 
-    scores, ids = insert_candidates(a.scores, a.ids, b.scores, b.ids)
+    with jax.named_scope("knn.topk"):
+        scores, ids = insert_candidates(a.scores, a.ids, b.scores, b.ids)
     return TopKState(scores=scores, ids=ids)
 
 
@@ -97,18 +99,19 @@ def tree_reduce_topk(state: TopKState, axis_name, num_shards: int) -> TopKState:
     the identical reduction, so the result is replicated — callable only
     inside ``shard_map``/``pmap`` tracing over ``axis_name``.
     """
-    all_scores = jax.lax.all_gather(state.scores, axis_name)  # (shards, N, k)
-    all_ids = jax.lax.all_gather(state.ids, axis_name)
-    states = [
-        TopKState(scores=all_scores[i], ids=all_ids[i]) for i in range(num_shards)
-    ]
-    while len(states) > 1:
-        nxt = [
-            merge_topk_states(states[i], states[i + 1])
-            if i + 1 < len(states) else states[i]
-            for i in range(0, len(states), 2)
+    with jax.named_scope("knn.topk"):
+        all_scores = jax.lax.all_gather(state.scores, axis_name)  # (shards, N, k)
+        all_ids = jax.lax.all_gather(state.ids, axis_name)
+        states = [
+            TopKState(scores=all_scores[i], ids=all_ids[i]) for i in range(num_shards)
         ]
-        states = nxt
+        while len(states) > 1:
+            nxt = [
+                merge_topk_states(states[i], states[i + 1])
+                if i + 1 < len(states) else states[i]
+                for i in range(0, len(states), 2)
+            ]
+            states = nxt
     return states[0]
 
 

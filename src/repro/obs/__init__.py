@@ -11,9 +11,8 @@
   recorder — the flight recorder: a bounded ring of recent spans and
              fault events that dumps JSONL on demand and automatically
              on fault.
-  profile  — opt-in `jax.profiler` capture around N batches, plus the
-             predicted-vs-measured FLOPs/bytes report over the store's
-             compiled fan-out program (launch/hlo_analysis).
+  profile  — opt-in `jax.profiler` capture around N batches; every span
+             is also a `knn.<name>` annotation on the profiler's timeline.
 """
 from repro.obs.recorder import FlightRecorder, get_recorder, set_recorder
 from repro.obs.registry import (
@@ -26,7 +25,7 @@ from repro.obs.registry import (
     set_registry,
 )
 from repro.obs.trace import Span, Tracer, default_tracer, set_tracing
-from repro.obs.profile import ProfileCapture, compiled_report, fanout_report
+from repro.obs.profile import ProfileCapture
 
 __all__ = [
     "Counter",
@@ -37,9 +36,7 @@ __all__ = [
     "ProfileCapture",
     "Span",
     "Tracer",
-    "compiled_report",
     "default_tracer",
-    "fanout_report",
     "get_recorder",
     "get_registry",
     "parse_exposition",

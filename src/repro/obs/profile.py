@@ -1,21 +1,12 @@
-"""Opt-in profiler hooks: ``jax.profiler`` capture + HLO roofline report.
+"""Opt-in profiler hook: ``jax.profiler`` capture around scheduler batches.
 
-Two independent tools, both off the hot path unless asked for:
-
-* :class:`ProfileCapture` — wraps ``jax.profiler.start_trace`` /
-  ``stop_trace`` around the next N scheduler batches.  The scheduler
-  calls ``on_batch_start``/``on_batch_end`` unconditionally; the hook is
-  inert until armed, and degrades to a no-op where the profiler backend
-  is unavailable (it must never take serving down).
-
-* :func:`compiled_report` / :func:`fanout_report` — predicted-vs-measured
-  FLOPs/bytes for a compiled program.  Predicted numbers come from
-  ``launch/hlo_analysis.py``'s trip-count-aware walk of the post-SPMD
-  HLO text (``while`` bodies multiplied out); measured numbers come from
-  XLA's own ``compiled.cost_analysis()`` (which counts loop bodies ONCE —
-  the ratio between the two is exactly the scan trip count the analysis
-  exists to recover).  ``fanout_report`` runs it on the store's ONE
-  jitted fan-out program via ``ShardedKNNStore.lowered_fanout``.
+:class:`ProfileCapture` wraps ``jax.profiler.start_trace`` /
+``stop_trace`` around the next N scheduler batches.  The scheduler calls
+``on_batch_start``/``on_batch_end`` unconditionally; the hook is inert
+until armed, and degrades to a no-op where the profiler backend is
+unavailable (it must never take serving down).  The trace it writes
+holds the tracer's spans as ``knn.*`` annotations and the scan's device
+ops under ``knn.*`` name scopes (see ``obs.trace``).
 """
 from __future__ import annotations
 
@@ -78,41 +69,3 @@ class ProfileCapture:
     def summary(self) -> dict:
         return {"logdir": self.logdir, "batches": self.seen,
                 "done": self.done, "error": self.error}
-
-
-def compiled_report(compiled, n_devices: int = 1) -> dict:
-    """Predicted (HLO-text walk) vs measured (XLA cost analysis)
-    FLOPs/bytes for one compiled program.  JSON-able; ``None`` fields
-    where a side is unavailable on this backend."""
-    from repro.launch import hlo_analysis
-
-    predicted = {"flops": None, "hbm_bytes": None}
-    measured = {"flops": None, "bytes_accessed": None}
-    try:
-        a = hlo_analysis.analyze(compiled.as_text(), n_devices=n_devices)
-        predicted = {"flops": a.flops, "hbm_bytes": a.hbm_bytes}
-    except Exception as e:  # noqa: BLE001 — report what we can
-        predicted["error"] = f"{type(e).__name__}: {e}"
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        measured = {"flops": ca.get("flops"),
-                    "bytes_accessed": ca.get("bytes accessed")}
-    except Exception as e:  # noqa: BLE001
-        measured["error"] = f"{type(e).__name__}: {e}"
-    out = {"predicted": predicted, "measured": measured}
-    if predicted.get("flops") and measured.get("flops"):
-        # > 1 when the program scans: cost_analysis counts while bodies once
-        out["flops_ratio_pred_over_meas"] = round(
-            predicted["flops"] / measured["flops"], 3)
-    return out
-
-
-def fanout_report(store, R, accuracy: Optional[str] = None) -> dict:
-    """Roofline report for the store's dispatched fan-out program at R's
-    block shape (the program ``store.query`` launches per R block)."""
-    import jax
-
-    lowered = store.lowered_fanout(R, accuracy=accuracy)
-    return compiled_report(lowered.compile(), n_devices=jax.device_count())

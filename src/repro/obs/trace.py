@@ -23,6 +23,13 @@ thread-local read and a None check.
 ``start_span``/``end_span`` are the non-pushing variant for leaf spans
 wrapped around loop bodies where a ``with`` block would force a reindent
 and nothing nests below them anyway.
+
+Every span is also on the profiler's timeline: beginning a span enters a
+``jax.profiler.TraceAnnotation`` named ``knn.<span name>`` on the
+beginning thread, and ending it exits that annotation, so a
+``jax.profiler`` trace shows the same tree (``knn.store.r_block`` over
+``knn.store.prep`` ...) beside the device ops.  Outside a profiler
+trace the annotation records nothing; a disabled tracer creates none.
 """
 from __future__ import annotations
 
@@ -32,7 +39,12 @@ import threading
 import time
 from typing import Iterator, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs import recorder as _recorder_mod
+
+#: prefix of a span's profiler annotation: ``knn.store.r_block``
+ANNOTATION_PREFIX = "knn."
 
 _ids = itertools.count(1)
 _local = threading.local()
@@ -41,13 +53,16 @@ _local = threading.local()
 class Span:
     """One timed operation.  ``attrs`` is small JSON-able metadata."""
 
-    __slots__ = ("name", "span_id", "parent_id", "t_start", "t_end", "attrs")
+    __slots__ = ("name", "span_id", "parent_id", "t_start", "t_end", "attrs",
+                 "_annotation")
 
     def __init__(self, name: str, span_id: int, parent_id: Optional[int],
                  **attrs):
         self.name = name
         self.span_id = span_id
         self.parent_id = parent_id
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
+        self._annotation.__enter__()
         self.t_start = time.monotonic()
         self.t_end: Optional[float] = None
         self.attrs = attrs
@@ -124,6 +139,7 @@ class Tracer:
         if span is None or span.t_end is not None:
             return span
         span.t_end = time.monotonic()
+        span._annotation.__exit__(None, None, None)
         if attrs:
             span.attrs.update(attrs)
         self._recorder().record_span(span)

@@ -867,9 +867,7 @@ class ShardedKNNStore:
         """Assemble the positional args of ONE fan-out call in the exact
         order ``_query_fn``'s program expects them: R-side block inputs,
         then (approx) the replicated band keys/valids, then the replica's
-        sharded stacks, then (approx) the shard LSH keys.  One definition
-        serves ``query()``'s dispatch loop AND ``lowered_fanout`` — the
-        orderings cannot drift apart."""
+        sharded stacks, then (approx) the shard LSH keys."""
         if self.algorithm == "bf":
             args = (br.indices, br.values, br.nnz)
         elif self.algorithm == "iib":
@@ -890,41 +888,6 @@ class ShardedKNNStore:
         if approx:
             args += (st["lshk"],)
         return args
-
-    def lowered_fanout(self, R: SparseBatch, accuracy: Optional[str] = None):
-        """Lower (without running) replica 0's jitted fan-out program at
-        ``R``'s resolved block shape — the hook ``obs.fanout_report`` uses
-        for the predicted-vs-measured FLOPs/bytes roofline
-        (``lowered.compile().as_text()`` feeds ``launch/hlo_analysis``)."""
-        acc = accuracy if accuracy is not None else self.spec.accuracy
-        approx = acc == "approx"
-        if approx and self._lsh is None:
-            raise ValueError("store has no LSH band tier; cannot lower the "
-                             "approx fan-out")
-        n_r = R.num_vectors
-        rb = min(self.spec.r_block or self.plan_for(R).r_block, n_r)
-        br, r_valid = _pad_block(R, 0, rb)
-        prep = None
-        if self.algorithm == "iib":
-            prep = prepare_r_block_inputs(br, "iib", self.tile)
-        elif self.algorithm == "iiib":
-            prep = prepare_r_block_inputs(
-                br, "iiib", self.tile,
-                rank_np=self._rank_np, rank_dev=self._rank_dev,
-            )
-        rk = rr = None
-        if approx:
-            stop = min(rb, n_r)
-            rk_np = np.zeros((rb, self._lsh.cfg.n_bands), np.int32)
-            rk_np[:stop] = self._lsh.keys_host(
-                np.asarray(R.indices[:stop]), np.asarray(R.values[:stop]))
-            rr_np = r_valid.copy()
-            rr_np[:stop] &= np.asarray(R.nnz[:stop]) > 0
-            rk, rr = jnp.asarray(rk_np), jnp.asarray(rr_np)
-        fn = self._query_fn(rb, 0, approx)
-        args = self._fanout_args(br, prep, r_valid, self._stacks[0],
-                                 approx, rk, rr)
-        return fn.lower(*args)
 
     def _occupied_tiles_of(self, idx: np.ndarray) -> int:
         """Dim-tiles the given rows touch (the engine's planner statistic)."""
@@ -1011,6 +974,144 @@ class ShardedKNNStore:
                 "replica_lost", replica=r, via="ReplicaLostError")
         self._replica_dirty[r] = set(range(self.n_shards))
 
+    def _prep_block(self, R: SparseBatch, r0: int, rb: int, approx: bool):
+        """Host prep of the R block at ``r0``: the padded block, its valid
+        mask, the R-side scan inputs and (approx) the band keys."""
+        br, r_valid = _pad_block(R, r0, rb)
+        prep = None
+        if self.algorithm == "iib":
+            prep = prepare_r_block_inputs(br, "iib", self.tile)
+        elif self.algorithm == "iiib":
+            prep = prepare_r_block_inputs(
+                br, "iiib", self.tile,
+                rank_np=self._rank_np, rank_dev=self._rank_dev,
+            )
+        rk = rr = None
+        if approx:
+            # R band keys are host-hashed from the raw R slice (same
+            # projection every shard/replica uses — identical keys to
+            # the single-device engine) and replicated into the program
+            stop = min(r0 + rb, R.num_vectors)
+            rk_np = np.zeros((rb, self._lsh.cfg.n_bands), np.int32)
+            rk_np[: stop - r0] = self._lsh.keys_host(
+                np.asarray(R.indices[r0:stop]), np.asarray(R.values[r0:stop])
+            )
+            rr_np = r_valid.copy()
+            rr_np[: stop - r0] &= np.asarray(R.nnz[r0:stop]) > 0
+            rk, rr = jnp.asarray(rk_np), jnp.asarray(rr_np)
+        return br, r_valid, prep, rk, rr
+
+    def _launch_block(self, br, prep, r_valid, rb: int, approx: bool, rk, rr,
+                      r0: int, allow_partial: bool):
+        """Launch one R block's fan-out program on a replica, failing over
+        as needed; returns (the program's asynchronous outputs, attempts,
+        the replica that took the block)."""
+        # failover loop: every failure tombstones a shard copy or kills
+        # a replica, so attempts are bounded by the copy count.  On an
+        # UNREPLICATED store `tried` stays empty and the loop marks the
+        # shard lost, raises without allow_partial, and redrives degraded
+        # with it.
+        tried: Set[int] = set()
+        last_err: Optional[Exception] = None
+        attempts = 0
+        while True:
+            order = [r for r in self._route_order() if r not in tried]
+            if not order:
+                exhausted = attempts > self.n_replicas * (self.n_shards + 2)
+                if not allow_partial or exhausted:
+                    if isinstance(last_err, ShardLostError):
+                        raise last_err
+                    raise ShardLostError(
+                        0,
+                        "no live replica can serve a full fan-out; "
+                        "recover() or resync_replicas()",
+                    ) from last_err
+                # degraded redrive: the best surviving copy answers with
+                # its lost shards masked out
+                tried.clear()
+                order = self._route_order()
+                if not order:
+                    raise ShardLostError(0, "all replicas dead") from last_err
+            r = order[0]
+            attempts += 1
+            probing = r in self.health.half_open()
+            if probing:
+                obs_recorder.get_recorder().record(
+                    "half_open_probe", replica=r, r0=r0)
+            self.stats.replica_dispatches[r] = (
+                self.stats.replica_dispatches.get(r, 0) + 1)
+            st = self._stacks[r]
+            fn = self._query_fn(rb, r, approx)
+            try:
+                if self.fault_plan is not None:
+                    self.fault_plan.on_dispatch(replica=r)
+                out = fn(*self._fanout_args(br, prep, r_valid, st,
+                                            approx, rk, rr))
+                self.health.record_success(r)
+                if tried:
+                    self.stats.replica_failovers += 1
+                    obs_recorder.get_recorder().fault(
+                        "replica_failover", replica=r, r0=r0,
+                        tried=sorted(tried))
+                return out, attempts, r
+            except ShardLostError as e:
+                last_err = e
+                if self.n_replicas == 1:
+                    self._mark_lost(e.shard)
+                    if not allow_partial:
+                        raise
+                else:
+                    self._note_shard_failure(r, e.shard)
+                    tried.add(r)
+            except ReplicaLostError as e:
+                if self.n_replicas == 1:
+                    raise
+                last_err = e
+                self._mark_replica_dead(r)
+                tried.add(r)
+
+    def _pull_block(self, out, prep, rb: int, approx: bool, stats: JoinStats):
+        """Pull one finished R block to the host: the IIIB threshold trace
+        and kept-entry count, the approx candidate counts and the block's
+        counters into ``stats``; returns its (scores, ids)."""
+        cand_cnt = None
+        if self.algorithm == "iiib":
+            if approx:
+                state, kept, thr, cand_cnt = out
+            else:
+                state, kept, thr = out
+        elif approx:
+            state, cand_cnt = out
+        else:
+            state = out
+        if self.algorithm == "iiib":
+            stats.list_entries += int(np.asarray(kept).sum())
+            thr_np = np.asarray(thr)
+            stats.min_prune_trace.append(thr_np)
+            observe_thresholds(thr_np)
+        if cand_cnt is not None:
+            # the counts ride the SAME program (all_gather outputs) —
+            # no extra dispatch, pulled with the block's result
+            stats.candidate_rows += int(np.asarray(cand_cnt).sum())
+            stats.scanned_rows += int(self._stacked_host["valid"].sum())
+        stats.device_dispatches += 1
+        stats.blocks += self._num_blocks_stacked * self.n_shards
+        if self.algorithm == "bf":
+            stats.dense_pairs += (
+                rb * self.s_block * self._num_blocks_stacked * self.n_shards
+            )
+        else:
+            stats.tiles_scored += (
+                int(prep["tiles"].shape[0])
+                * self._num_blocks_stacked * self.n_shards
+            )
+            if self.algorithm == "iib":
+                stats.list_entries += sum(
+                    blk.list_total for s in self.shards for blk in s._blocks
+                )
+        stats.host_syncs += 1                # the R block's result pull
+        return np.asarray(state.scores), np.asarray(state.ids)
+
     def query(
         self,
         R: SparseBatch,
@@ -1067,135 +1168,26 @@ class ShardedKNNStore:
         out_scores, out_ids = [], []
         served_missing: Set[int] = set()
         for r0 in range(0, n_r, rb):
-            # leaf span per dispatched R block; parents to the serving
-            # batch/dispatch span when one is active on this thread
-            _sp = obs_trace.start_span("store.r_block", r0=r0,
-                                       algorithm=self.algorithm)
-            br, r_valid = _pad_block(R, r0, rb)
-            prep = None
-            if self.algorithm == "iib":
-                prep = prepare_r_block_inputs(br, "iib", self.tile)
-            elif self.algorithm == "iiib":
-                prep = prepare_r_block_inputs(
-                    br, "iiib", self.tile,
-                    rank_np=self._rank_np, rank_dev=self._rank_dev,
-                )
-            cand_cnt = None
-            rk = rr = None
-            if approx:
-                # R band keys are host-hashed from the raw R slice (same
-                # projection every shard/replica uses — identical keys to
-                # the single-device engine) and replicated into the program
-                stop = min(r0 + rb, n_r)
-                rk_np = np.zeros((rb, self._lsh.cfg.n_bands), np.int32)
-                rk_np[: stop - r0] = self._lsh.keys_host(
-                    np.asarray(R.indices[r0:stop]), np.asarray(R.values[r0:stop])
-                )
-                rr_np = r_valid.copy()
-                rr_np[: stop - r0] &= np.asarray(R.nnz[r0:stop]) > 0
-                rk, rr = jnp.asarray(rk_np), jnp.asarray(rr_np)
-            # failover loop: every failure tombstones a shard copy or kills
-            # a replica, so attempts are bounded by the copy count.  On an
-            # UNREPLICATED store `tried` stays empty and this is exactly the
-            # PR 7 loop: mark lost, raise without allow_partial, redrive
-            # degraded with it.
-            tried: Set[int] = set()
-            last_err: Optional[Exception] = None
-            attempts = 0
-            while True:
-                order = [r for r in self._route_order() if r not in tried]
-                if not order:
-                    exhausted = attempts > self.n_replicas * (self.n_shards + 2)
-                    if not allow_partial or exhausted:
-                        if isinstance(last_err, ShardLostError):
-                            raise last_err
-                        raise ShardLostError(
-                            0,
-                            "no live replica can serve a full fan-out; "
-                            "recover() or resync_replicas()",
-                        ) from last_err
-                    # degraded redrive: the best surviving copy answers with
-                    # its lost shards masked out
-                    tried.clear()
-                    order = self._route_order()
-                    if not order:
-                        raise ShardLostError(0, "all replicas dead") from last_err
-                r = order[0]
-                attempts += 1
-                probing = r in self.health.half_open()
-                if probing:
-                    obs_recorder.get_recorder().record(
-                        "half_open_probe", replica=r, r0=r0)
-                self.stats.replica_dispatches[r] = (
-                    self.stats.replica_dispatches.get(r, 0) + 1)
-                st = self._stacks[r]
-                fn = self._query_fn(rb, r, approx)
-                try:
-                    if self.fault_plan is not None:
-                        self.fault_plan.on_dispatch(replica=r)
-                    out = fn(*self._fanout_args(br, prep, r_valid, st,
-                                                approx, rk, rr))
-                    if self.algorithm == "iiib":
-                        if approx:
-                            state, kept, thr, cand_cnt = out
-                        else:
-                            state, kept, thr = out
-                    elif approx:
-                        state, cand_cnt = out
-                    else:
-                        state = out
-                    self.health.record_success(r)
-                    if tried:
-                        self.stats.replica_failovers += 1
-                        obs_recorder.get_recorder().fault(
-                            "replica_failover", replica=r, r0=r0,
-                            tried=sorted(tried))
-                    served_missing |= self._lost[r]
-                    break
-                except ShardLostError as e:
-                    last_err = e
-                    if self.n_replicas == 1:
-                        self._mark_lost(e.shard)
-                        if not allow_partial:
-                            raise
-                    else:
-                        self._note_shard_failure(r, e.shard)
-                        tried.add(r)
-                except ReplicaLostError as e:
-                    if self.n_replicas == 1:
-                        raise
-                    last_err = e
-                    self._mark_replica_dead(r)
-                    tried.add(r)
-            if self.algorithm == "iiib":
-                stats.list_entries += int(np.asarray(kept).sum())
-                thr_np = np.asarray(thr)
-                stats.min_prune_trace.append(thr_np)
-                observe_thresholds(thr_np)
-            if cand_cnt is not None:
-                # the counts ride the SAME program (all_gather outputs) —
-                # no extra dispatch, pulled with the block's result
-                stats.candidate_rows += int(np.asarray(cand_cnt).sum())
-                stats.scanned_rows += int(self._stacked_host["valid"].sum())
-            stats.device_dispatches += 1
-            stats.blocks += self._num_blocks_stacked * self.n_shards
-            if self.algorithm == "bf":
-                stats.dense_pairs += (
-                    rb * self.s_block * self._num_blocks_stacked * self.n_shards
-                )
-            else:
-                stats.tiles_scored += (
-                    int(prep["tiles"].shape[0])
-                    * self._num_blocks_stacked * self.n_shards
-                )
-                if self.algorithm == "iib":
-                    stats.list_entries += sum(
-                        blk.list_total for s in self.shards for blk in s._blocks
-                    )
-            out_scores.append(np.asarray(state.scores)[r_valid])
-            out_ids.append(np.asarray(state.ids)[r_valid])
-            stats.host_syncs += 1                # the R block's result pull
-            obs_trace.end_span(_sp, attempts=attempts)
+            # one span per dispatched R block (parented to the serving
+            # batch/dispatch span when one is active on this thread), split
+            # into host prep, the asynchronous launch, the one wait for the
+            # device, and the result pull
+            with obs_trace.span("store.r_block", r0=r0,
+                                algorithm=self.algorithm) as _sp:
+                with obs_trace.span("store.prep"):
+                    br, r_valid, prep, rk, rr = self._prep_block(R, r0, rb, approx)
+                with obs_trace.span("store.launch"):
+                    out, attempts, r = self._launch_block(
+                        br, prep, r_valid, rb, approx, rk, rr, r0, allow_partial)
+                served_missing |= self._lost[r]
+                with obs_trace.span("store.wait"):
+                    jax.block_until_ready(out)
+                with obs_trace.span("store.pull"):
+                    scores, ids = self._pull_block(out, prep, rb, approx, stats)
+                    out_scores.append(scores[r_valid])
+                    out_ids.append(ids[r_valid])
+                if _sp is not None:
+                    _sp.attrs["attempts"] = attempts
         dt = time.perf_counter() - t_q
         stats.query_wall_s += dt
         self.stats.query_wall_s += dt

@@ -342,3 +342,116 @@ def test_iiib_query_populates_prune_trace():
     from repro.obs.registry import get_registry
     hist = get_registry().get("knn_min_prune_threshold")
     assert hist is not None and hist.count >= 1
+
+
+# ---------------------------------------------------------------------------
+# the profiler's timeline: span annotations and device name scopes
+# ---------------------------------------------------------------------------
+
+STORE_PHASES = ["knn.store.prep", "knn.store.launch", "knn.store.wait",
+                "knn.store.pull"]
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` trace; returns its result and
+    the trace's ``knn.*`` host events as (start_ns, end_ns, name)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+        jax.block_until_ready(out)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                events.extend(
+                    (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    for e in line.events if e.name.startswith("knn."))
+    return out, sorted(events)
+
+
+def _iiib_store():
+    S = synthetic_sparse(64, dim=64, nnz_mean=8, seed=3)
+    R = synthetic_sparse(16, dim=64, nnz_mean=8, seed=4)
+    store = ShardedKNNStore.build(
+        S, JoinSpec(k=4, algorithm="iiib", r_block=8, s_block=32))
+    store.query(R)                      # compile outside the traces
+    return store, R
+
+
+def test_store_phases_on_profiler_timeline(tmp_path):
+    """Each R block is a knn.store.r_block annotation holding its four
+    phases, nested and in order, on the profiler's timeline."""
+    store, R = _iiib_store()
+    _, events = _profiled(tmp_path, lambda: store.query(R).ids)
+    blocks = [e for e in events if e[2] == "knn.store.r_block"]
+    assert len(blocks) == 2             # 16 rows in R blocks of 8
+    for lo, hi, _ in blocks:
+        inside = [e for e in events if lo <= e[0] and e[1] <= hi
+                  and e[2] != "knn.store.r_block"]
+        assert [e[2] for e in inside] == STORE_PHASES
+        assert all(a[1] <= b[0] for a, b in zip(inside, inside[1:]))
+
+
+def test_tracing_off_leaves_profiler_timeline_empty(tmp_path):
+    """set_tracing(False) creates no annotation, and the answers do not
+    change by a bit with the annotations on."""
+    store, R = _iiib_store()
+    on, on_events = _profiled(tmp_path / "on", lambda: store.query(R))
+    set_tracing(False)
+    try:
+        off, off_events = _profiled(tmp_path / "off", lambda: store.query(R))
+    finally:
+        set_tracing(True)
+    assert {e[2] for e in on_events} >= {"knn.store.r_block", *STORE_PHASES}
+    assert off_events == []
+    np.testing.assert_array_equal(np.asarray(on.ids), np.asarray(off.ids))
+    np.testing.assert_array_equal(
+        np.asarray(on.scores), np.asarray(off.scores))
+
+
+def test_start_end_span_annotate_with_the_recorder_unchanged(tmp_path):
+    """The non-pushing pair annotates too; the recorder's span dict keeps
+    its fields."""
+    rec = FlightRecorder()
+    tr = Tracer(recorder=rec)
+
+    def work():
+        s = tr.begin("ckpt.load", step=3)
+        tr.end(s, n_shards=1)
+        return np.zeros(1)
+
+    _, events = _profiled(tmp_path, work)
+    assert [e[2] for e in events] == ["knn.ckpt.load"]
+    (ev,) = rec.events()
+    assert set(ev) == {"t_wall", "kind", "name", "span_id", "parent_id",
+                       "t_start", "t_end", "dur_ms", "attrs"}
+    assert ev["attrs"] == {"step": 3, "n_shards": 1}
+
+
+@pytest.mark.parametrize("algorithm,scopes", [
+    ("bf", {"knn.matmul", "knn.topk"}),
+    ("iib", {"knn.matmul", "knn.scatter", "knn.topk"}),
+    ("iiib", {"knn.matmul", "knn.scatter", "knn.bound", "knn.topk"}),
+])
+def test_fanout_ops_carry_scan_scopes(algorithm, scopes):
+    """The compiled fan-out program's ops name their scan phase in their
+    op_name metadata, which the profiler's device trace carries."""
+    import re
+
+    S = synthetic_sparse(64, dim=64, nnz_mean=8, seed=3)
+    R = synthetic_sparse(8, dim=64, nnz_mean=8, seed=4)
+    store = ShardedKNNStore.build(
+        S, JoinSpec(k=4, algorithm=algorithm, r_block=8, s_block=32))
+    br, r_valid, prep, rk, rr = store._prep_block(R, 0, 8, False)
+    args = store._fanout_args(br, prep, r_valid, store._stacks[0], False)
+    text = store._query_fn(8).lower(*args).compile().as_text()
+    found = set(re.findall(r'op_name="[^"]*?(knn\.[a-z]+)/', text))
+    assert found == scopes
