@@ -134,7 +134,7 @@ class JoinStats:
     """Work accounting for the paper's cost-model comparisons (C2 vs C3)."""
 
     blocks: int = 0
-    tiles_scored: int = 0          # (tile-matmul count) — IIB/IIIB indexed work
+    tiles_scored: int = 0          # Σ tiles scored over S blocks: IIB's active ones, IIIB's all T
     list_entries: int = 0          # Σ list entries actually scored (IIIB: unmasked only)
     dense_pairs: int = 0           # BF full-score pairs
     index_builds: int = 0          # S-block index constructions (build-once observable)
@@ -347,12 +347,10 @@ def _pad_block(batch: SparseBatch, start: int, size: int) -> Tuple[SparseBatch, 
     return block, valid
 
 
-def _host_tile_any(block: SparseBatch, tile: int, t_total: int, rank: Optional[np.ndarray] = None) -> np.ndarray:
-    """(T,) bool — does ANY row of the block touch dim-tile t (permuted space)?"""
+def _host_tile_any(block: SparseBatch, tile: int, t_total: int) -> np.ndarray:
+    """(T,) bool — does ANY row of the block touch dim-tile t?"""
     idx = np.asarray(block.indices)
     valid = idx < block.dim
-    if rank is not None:
-        idx = np.where(valid, rank[np.minimum(idx, block.dim - 1)], block.dim)
     tid = np.where(valid, idx // tile, t_total)
     out = np.zeros(t_total + 1, dtype=bool)
     out[np.minimum(tid.ravel(), t_total)] = True
@@ -412,23 +410,22 @@ def prepare_r_block_inputs(
     br: SparseBatch,
     algorithm: str,
     tile: int,
-    rank_np: Optional[np.ndarray] = None,
     rank_dev: Optional[jax.Array] = None,
     with_r_tiles: bool = True,
 ) -> dict:
     """R-side device inputs of one padded R block's scan step.
 
     The single home of the per-R-block preparation the scanned drivers
-    consume — dense (rank-permuted) R tiles, the host-derived active-tile
+    consume — dense (rank-permuted) R tiles, IIB's host-derived active-tile
     list, and IIIB's per-tile maxWeight bound.  Shared by the engine's
     query loop and by :class:`repro.store.ShardedKNNStore`, whose fan-out
     replicates exactly these inputs to every shard (they depend only on R
     and on build-frozen datastore statistics, never on the S shard).
     """
-    t_total = num_tiles(br.dim, tile)
     if algorithm == "bf":
         return {}
     if algorithm == "iib":
+        t_total = num_tiles(br.dim, tile)
         # the streaming kernel path needs only the active-tile list (the
         # fused kernel densifies its own R tiles) — with_r_tiles=False
         # skips the O(T·|Br|·tile) densify + upload
@@ -437,11 +434,10 @@ def prepare_r_block_inputs(
         if with_r_tiles:
             out["r_tiles"] = dense_r_tiles(br, None, tile)
         return out
-    occ_any = _host_tile_any(br, tile, t_total, rank_np)
+    # IIIB's dense product scores every tile: no active-tile list
     return {
         "r_tiles": dense_r_tiles(br, rank_dev, tile),
         "mwt": iiib_mod.maxw_tiles(br, rank_dev, tile),
-        "tiles": jnp.asarray(active_tile_list(occ_any)),
     }
 
 
@@ -1212,17 +1208,17 @@ class SparseKNNIndex:
                         )
             else:  # iiib — masked superset refinement, threshold in carry
                 prep = prepare_r_block_inputs(
-                    br, "iiib", tile, rank_np=self._rank_np, rank_dev=self._rank_dev
+                    br, "iiib", tile, rank_dev=self._rank_dev
                 )
-                r_tiles, mwt, tiles = prep["r_tiles"], prep["mwt"], prep["tiles"]
+                r_tiles, mwt = prep["r_tiles"], prep["mwt"]
                 rv = jnp.asarray(r_valid)
                 if cached:
                     state, aux = self._query_iiib_scanned(
-                        state, r_tiles, mwt, tiles, stats, sampled_mask, rv, cand
+                        state, r_tiles, mwt, stats, sampled_mask, rv, cand
                     )
                 else:
                     state = self._query_pairs_iiib(
-                        state, r_tiles, mwt, tiles, stats, sampled_mask, rv,
+                        state, r_tiles, mwt, stats, sampled_mask, rv,
                         cand_np,
                     )
 
@@ -1298,7 +1294,7 @@ class SparseKNNIndex:
         return v
 
     def _query_iiib_scanned(
-        self, state, r_tiles, mwt, tiles, stats, sampled_mask, rv, cand=None
+        self, state, r_tiles, mwt, stats, sampled_mask, rv, cand=None
     ):
         """IIIB's whole S side as ONE dispatch: the superset-index scan with
         (TopKState, MinPruneScore) in the carry.  The warm-started threshold
@@ -1312,14 +1308,14 @@ class SparseKNNIndex:
         if cand is not None:
             s_valid = jnp.logical_and(s_valid, cand)
         state, _, thr_trace, kept = iiib_scan_join(
-            state, thr0, r_tiles, mwt, tiles,
+            state, thr0, r_tiles, mwt,
             st.rows, st.vals, st.counts, self._mass_stack, st.ids,
             s_valid, rv,
             tile=self.tile, num_s=self.s_block,
         )
         stats.device_dispatches += 1
         stats.blocks += b
-        stats.tiles_scored += int(tiles.shape[0]) * b
+        stats.tiles_scored += int(r_tiles.shape[0]) * b
         # trace = [seed, after block 0, ..., after block B-1]  (B+1 values)
         return state, {"thr": jnp.concatenate([thr0[None], thr_trace]), "kept": kept}
 
@@ -1436,7 +1432,7 @@ class SparseKNNIndex:
         return state
 
     def _query_pairs_iiib(
-        self, state, r_tiles, mwt, tiles, stats, sampled_mask, rv, cand_np=None
+        self, state, r_tiles, mwt, stats, sampled_mask, rv, cand_np=None
     ):
         """Streaming IIIB: the same masked-superset step as the scan, driven
         per pair — the superset index materializes transiently per (B_r,
@@ -1461,11 +1457,11 @@ class SparseKNNIndex:
             stats.host_syncs += 1
             state, _, kept = iiib_masked_block(
                 state, thr, r_tiles, index, jnp.asarray(blk.tilemass), mwt,
-                tiles, jnp.int32(blk.start), jnp.asarray(s_valid[bi]), rv,
+                jnp.int32(blk.start), jnp.asarray(s_valid[bi]), rv,
             )
             stats.device_dispatches += 2
             stats.blocks += 1
-            stats.tiles_scored += int(tiles.shape[0])
+            stats.tiles_scored += int(r_tiles.shape[0])
             stats.list_entries += int(np.asarray(kept))
             stats.host_syncs += 1
         return state

@@ -15,7 +15,7 @@ Two exact variants:
   the whole S side of an R block runs as one jitted ``lax.scan`` whose
   carry holds the TopKState AND the threshold.  Candidate completion
   (paper lines 20-24) needs no separate rescue pass: the superset lists
-  already hold the "unindexed" mass, so the same per-tile matmuls yield
+  already hold the "unindexed" mass, so the same list values yield
   both the indexed score A (masked accumulate — what the candidate test
   reads) and the exact dot product (full accumulate — what enters the
   top-k).
@@ -134,7 +134,6 @@ def _masked_block(
     index: TileIndex,          # threshold-FREE superset index of the S block
     tilemass: jax.Array,       # (|Bs|, T) per-row per-tile value mass
     maxw_tile: jax.Array,      # (T,) per-tile maxWeight(B_r)
-    active_tiles: jax.Array,   # (A,) int32, sentinel-padded
     s_offset: jax.Array,       # scalar first-row id or (|Bs|,) per-row global ids
     s_valid: jax.Array,        # (|Bs|,) bool — padding, tombstoned AND sampled rows
     r_valid: jax.Array,        # (|Br|,) bool — masks padded R rows out of the min
@@ -152,7 +151,7 @@ def _masked_block(
         cum = jnp.cumsum(contrib, axis=1)              # inclusive prefix bound
         keep = cum > thr                               # entry (s, t) stays indexed
         pref_ub = jnp.sum(jnp.where(keep, 0.0, contrib), axis=1)
-    a_kept, a_full = masked_tile_scores(r_tiles, index, active_tiles, keep)
+    a_kept, a_full = masked_tile_scores(r_tiles, index, keep)
     prune = prune_scores(state)
     # Theorem 1 (shared kept feature) + the A + prefUB > pruneScore bound;
     # offered value is the EXACT dot (a_full) — completion without rescue
@@ -178,7 +177,6 @@ def iiib_scan_join(
     thr: jax.Array,            # scalar f32 — seed threshold (warm start stays on device)
     r_tiles: jax.Array,        # (T, |Br|, tile)
     maxw_tile: jax.Array,      # (T,)
-    active_tiles: jax.Array,   # (A,) int32, sentinel-padded (shared by all blocks)
     s_rows: jax.Array,         # (B, T+1, M) int32 — stacked superset tile lists
     s_vals: jax.Array,         # (B, T+1, M, tile) f32
     s_counts: jax.Array,       # (B, T+1) int32
@@ -208,8 +206,7 @@ def iiib_scan_join(
             crossing=crossing, tile=tile, num_s=num_s,
         )
         st, th, kept = _masked_block(
-            st, th, r_tiles, index, mass, maxw_tile, active_tiles, ids, vm,
-            r_valid,
+            st, th, r_tiles, index, mass, maxw_tile, ids, vm, r_valid,
         )
         return (st, th), (th, kept)
 

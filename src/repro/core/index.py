@@ -7,7 +7,11 @@ index stores the list of S rows with any (indexed) mass in that tile,
 together with a densified ``(row, tile)`` value patch.  Scoring a tile is
 then one MXU matmul ``(|Br|, tile) @ (tile, M)`` plus a column scatter-add
 into the accumulator — work proportional to the *list length* ``M``, not
-|Bs|, exactly the paper's C3 structure.
+|Bs|, exactly the paper's C3 structure.  Every list of a block is padded to
+one ``M``, so the cost is ∝ ``M`` per tile.  ``M`` is close to |Bs| on the
+shipped configurations, so the IIIB scan instead puts the patches back in
+S-row order and scores the block with one dense product
+(``masked_tile_scores``).
 
 The same builder implements IIIB's threshold refinement (§4.4): features
 are walked in descending frequency(B_r) order accumulating the trivial
@@ -271,10 +275,25 @@ def tile_scores(
     return acc[:, : index.num_s]
 
 
+# The dense scan's temporaries, per S block: the densified S, its kept copy,
+# and the two stacked for the product, each (T, |Bs|, tile) float32 (the
+# stack twice that): at most 4 · T · |Bs| · tile · 4 B.  At synth50k's
+# geometry (T = 79, |Bs| = 4,096) that is 0.66 GB; a described v5e compile
+# of the store's fan-out there holds 0.49 GB of temporaries in all.  The
+# bound allows a quarter of one v5e's 16 GB: with |Bs| = 4,096, up to
+# T = 512 tiles (dim 65,536); a larger dim needs a smaller s_block.
+DENSE_SCAN_MAX_BYTES = 4 * 2**30
+
+
+def dense_scan_bytes(t_total: int, num_s: int, tile: int) -> int:
+    """Upper bound of ``masked_tile_scores``'s temporaries for one S block
+    of ``num_s`` rows over ``t_total`` tiles of ``tile`` dims."""
+    return 4 * t_total * num_s * tile * 4
+
+
 def masked_tile_scores(
     r_dense_tiles: jax.Array,    # (T, |Br|, tile) — permuted-dim dense tiles of B_r
     index: TileIndex,
-    active_tiles: jax.Array,     # (A,) int32 tile ids; pad with n_tiles (sentinel)
     keep: jax.Array,             # (|Bs|, T) bool — entry (s, t) survives the threshold
 ) -> Tuple[jax.Array, jax.Array]:
     """IIIB threshold refinement as an on-device mask over a superset index.
@@ -282,7 +301,7 @@ def masked_tile_scores(
     ``index`` is a threshold-FREE index (every feature indexed); ``keep``
     encodes the live MinPruneScore refinement (``prefix_bound > threshold``
     per (row, tile) — see core/iiib.py).  Returns two (|Br|, |Bs|) score
-    accumulators from the SAME per-tile matmuls:
+    accumulators from the SAME list values:
 
       kept: Σ over unmasked entries — the paper's indexed-feature score A,
             what the candidate test (Theorem 1 + bound check) reads;
@@ -292,39 +311,58 @@ def masked_tile_scores(
             separate rescue pass: the "unindexed" mass is already sitting
             in the masked-out slots of the same lists).
 
-    One matmul per tile either way — the mask costs one select + one extra
-    scatter-add, not extra MXU work.
+    The list slots' patches go back in S-row order, once per S block, and
+    both accumulators come from one product against the kept and the full
+    operand.  Every list is padded to one ``M``, about |Bs| on the shipped
+    configurations, so per-tile list products would do nearly the dense
+    work and add a column scatter of each.  Inactive tiles hold zero R
+    mass, so scoring all T tiles gives the sums of the active ones.  Raises
+    where the temporaries would pass ``DENSE_SCAN_MAX_BYTES``.
     """
-    n_r = r_dense_tiles.shape[1]
-    t_total = r_dense_tiles.shape[0]
-    r_pad = jnp.concatenate(
-        [r_dense_tiles, jnp.zeros((1,) + r_dense_tiles.shape[1:], r_dense_tiles.dtype)], axis=0
-    )
-    # sentinel row (id num_s) and sentinel tile column: never kept
+    t_total, n_r, tile = r_dense_tiles.shape
+    n_s = index.num_s
+    need = dense_scan_bytes(t_total, n_s, tile)
+    if need > DENSE_SCAN_MAX_BYTES:
+        raise ValueError(
+            f"IIIB scan temporaries of {need / 2**30:.1f} GiB per S block "
+            f"({t_total} tiles x {n_s} rows) pass the "
+            f"{DENSE_SCAN_MAX_BYTES / 2**30:.0f} GiB bound; use a smaller "
+            "s_block"
+        )
+    with jax.named_scope("knn.scatter"):
+        rows = index.rows[:t_total]                              # (T, M)
+        slot = jnp.arange(t_total, dtype=jnp.int32)[:, None] * n_s + rows
+        # sentinel slots go to distinct out-of-range rows, which the scatter
+        # drops, so every index is unique
+        spare = t_total * n_s + jnp.arange(rows.size, dtype=jnp.int32)
+        slot = jnp.where(rows < n_s, slot, spare.reshape(rows.shape))
+        s_dense = jnp.zeros((t_total * n_s, tile), jnp.float32).at[
+            slot.reshape(-1)
+        ].set(
+            index.vals[:t_total].reshape(-1, tile),
+            mode="drop", unique_indices=True,
+        ).reshape(t_total, n_s, tile)
     with jax.named_scope("knn.bound"):
-        kp = jnp.zeros((index.num_s + 1, t_total + 1), bool)
-        kp = kp.at[: index.num_s, :t_total].set(keep)
+        s_kept = jnp.where(keep.T[:, :, None], s_dense, 0.0)
+        both = jnp.concatenate([s_kept, s_dense], axis=1)      # (T, 2|Bs|, tile)
 
-    def body(accs, t):
-        acc_kept, acc_full = accs
-        rt = r_pad[t]                       # (|Br|, tile)
-        v = index.vals[t]                   # (M, tile)
-        with jax.named_scope("knn.matmul"):
-            p = jax.lax.dot_general(
-                rt, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST,
-            )                               # (|Br|, M)
-        rows_t = index.rows[t]
-        with jax.named_scope("knn.bound"):
-            keep_t = kp[rows_t, jnp.minimum(t, t_total)]
-        with jax.named_scope("knn.scatter"):
-            acc_full = acc_full.at[:, rows_t].add(p)
-            acc_kept = acc_kept.at[:, rows_t].add(jnp.where(keep_t[None, :], p, 0.0))
-        return (acc_kept, acc_full), None
+    def flat(x):  # (T, n, tile) -> (n, T·tile)
+        return jnp.transpose(x, (1, 0, 2)).reshape(x.shape[1], -1)
 
-    acc0 = jnp.zeros((n_r, index.num_s + 1), jnp.float32)
-    (acc_kept, acc_full), _ = jax.lax.scan(body, (acc0, acc0), active_tiles)
-    return acc_kept[:, : index.num_s], acc_full[:, : index.num_s]
+    # One contracting axis makes each score sum its row pair in one order
+    # wherever the pair sits in the block; a one-row block would take a
+    # matrix-vector product, which sums in another order, so it gets a
+    # zero second row
+    r_rows = flat(r_dense_tiles)
+    if n_r == 1:
+        r_rows = jnp.pad(r_rows, ((0, 1), (0, 0)))
+    with jax.named_scope("knn.matmul"):
+        acc = jax.lax.dot_general(
+            r_rows, flat(both), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )[:n_r]                                                  # (|Br|, 2|Bs|)
+    return acc[:, :n_s], acc[:, n_s:]
 
 
 def dense_r_tiles(r_block: SparseBatch, rank: Optional[jax.Array], tile: int = DEFAULT_TILE) -> jax.Array:

@@ -115,7 +115,7 @@ from repro.core.topk import TopKState, init_topk, tree_reduce_topk
 from repro.obs import recorder as obs_recorder
 from repro.obs import trace as obs_trace
 from repro.runtime.fault import ReplicaHealth, ReplicaLostError, ShardLostError
-from repro.sparse.format import SparseBatch
+from repro.sparse.format import SparseBatch, num_tiles
 
 P = jax.sharding.PartitionSpec
 
@@ -297,13 +297,13 @@ def fanout_program(algorithm: str, mesh, axes: Tuple[str, ...], *, rb: int,
             out_specs=(state_spec, rep),
         )
     elif not approx:
-        def local(r_tiles, mwt, tiles, rv,
+        def local(r_tiles, mwt, rv,
                   s_rows, s_vals, s_counts, s_mass, s_ids, s_valid):
             state = init_topk(rb, k)
             # each shard carries its OWN MinPruneScore — work-only
             # divergence from the sequential scan (see module docstring)
             state, thr, _, kept = iiib_scan_join(
-                state, jnp.float32(-jnp.inf), r_tiles, mwt, tiles,
+                state, jnp.float32(-jnp.inf), r_tiles, mwt,
                 s_rows[0], s_vals[0], s_counts[0], s_mass[0], s_ids[0],
                 s_valid[0], rv, tile=tile, num_s=sb,
             )
@@ -316,17 +316,17 @@ def fanout_program(algorithm: str, mesh, axes: Tuple[str, ...], *, rb: int,
 
         fn = jax.shard_map(
             local, mesh=mesh, check_vma=False,
-            in_specs=(rep, rep, rep, rep) + (shard,) * 6,
+            in_specs=(rep,) * 3 + (shard,) * 6,
             out_specs=(state_spec, rep, rep),
         )
     else:
-        def local(r_tiles, mwt, tiles, rv, rk, rr,
+        def local(r_tiles, mwt, rv, rk, rr,
                   s_rows, s_vals, s_counts, s_mass, s_ids, s_valid, s_lshk):
             vm = jnp.logical_and(
                 s_valid[0], lsh_mod.band_hits(rk, rr, s_lshk[0]))
             state = init_topk(rb, k)
             state, thr, _, kept = iiib_scan_join(
-                state, jnp.float32(-jnp.inf), r_tiles, mwt, tiles,
+                state, jnp.float32(-jnp.inf), r_tiles, mwt,
                 s_rows[0], s_vals[0], s_counts[0], s_mass[0], s_ids[0],
                 vm, rv, tile=tile, num_s=sb,
             )
@@ -340,7 +340,7 @@ def fanout_program(algorithm: str, mesh, axes: Tuple[str, ...], *, rb: int,
 
         fn = jax.shard_map(
             local, mesh=mesh, check_vma=False,
-            in_specs=(rep,) * 6 + (shard,) * 7,
+            in_specs=(rep,) * 5 + (shard,) * 7,
             out_specs=(state_spec, rep, rep, rep),
         )
     return jax.jit(fn, compiler_options=submesh_compiler_options(mesh))
@@ -873,8 +873,7 @@ class ShardedKNNStore:
         elif self.algorithm == "iib":
             args = (prep["r_tiles"], prep["tiles"])
         else:  # iiib
-            args = (prep["r_tiles"], prep["mwt"], prep["tiles"],
-                    jnp.asarray(r_valid))
+            args = (prep["r_tiles"], prep["mwt"], jnp.asarray(r_valid))
         if approx:
             args += (rk, rr)
         if self.algorithm == "bf":
@@ -983,9 +982,7 @@ class ShardedKNNStore:
             prep = prepare_r_block_inputs(br, "iib", self.tile)
         elif self.algorithm == "iiib":
             prep = prepare_r_block_inputs(
-                br, "iiib", self.tile,
-                rank_np=self._rank_np, rank_dev=self._rank_dev,
-            )
+                br, "iiib", self.tile, rank_dev=self._rank_dev)
         rk = rr = None
         if approx:
             # R band keys are host-hashed from the raw R slice (same
@@ -1101,10 +1098,11 @@ class ShardedKNNStore:
                 rb * self.s_block * self._num_blocks_stacked * self.n_shards
             )
         else:
+            # IIB scores the active-tile list; IIIB's dense product all T
+            tiles = (int(prep["tiles"].shape[0]) if self.algorithm == "iib"
+                     else num_tiles(self.dim, self.tile))
             stats.tiles_scored += (
-                int(prep["tiles"].shape[0])
-                * self._num_blocks_stacked * self.n_shards
-            )
+                tiles * self._num_blocks_stacked * self.n_shards)
             if self.algorithm == "iib":
                 stats.list_entries += sum(
                     blk.list_total for s in self.shards for blk in s._blocks

@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at import,
 so every test worker collects the same tests and only the worker given this
 file loads the TPU library.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -102,7 +104,7 @@ def test_store_iiib_fanout_compiles_for_v5e(topo, monkeypatch, n_shards):
     rep, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P("shard"))
     # 10,000 rows split over the shards, S blocks of at most 2,048 rows
     b = -(-NS // SYNTHETIC.s_block // n_shards)
-    sb, a_len = SYNTHETIC.s_block, 80
+    sb = SYNTHETIC.s_block
 
     def arg(shape, dtype, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -110,7 +112,6 @@ def test_store_iiib_fanout_compiles_for_v5e(topo, monkeypatch, n_shards):
     args = (
         arg((T, NR, SYNTHETIC.tile), jnp.float32, rep),       # dense R tiles
         arg((T,), jnp.float32, rep),                          # maxWeight per tile
-        arg((a_len,), jnp.int32, rep),                        # active tiles
         arg((NR,), jnp.bool_, rep),                           # real R rows
         arg((n_shards, b, T + 1, M), jnp.int32, shard),       # list rows
         arg((n_shards, b, T + 1, M, SYNTHETIC.tile), jnp.float32, shard),
@@ -122,4 +123,54 @@ def test_store_iiib_fanout_compiles_for_v5e(topo, monkeypatch, n_shards):
     compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     # the index stacks dominate; everything must fit one 16 GB chip
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def _scatter_update_sizes(hlo: str):
+    """Element counts of the update operands of every scatter in a
+    compiled HLO module's text (operands are printed by name only, so
+    their shapes are looked up from definitions and parameters)."""
+    shapes = dict(re.findall(r"%?([\w.-]+) = \(?(\w+\[[\d,]*\])", hlo))
+    shapes.update(re.findall(r"([\w.-]+): (\w+\[[\d,]*\])", hlo))
+    sizes = []
+    for args in re.findall(r" scatter\(([^)]*)\)", hlo):
+        for name in re.findall(r"%([\w.-]+)", args)[2:]:
+            dims = re.search(r"\[([\d,]*)\]", shapes[name]).group(1)
+            sizes.append(int(np.prod([int(d) for d in dims.split(",") if d])))
+    return sizes
+
+
+def test_store_iiib_fanout_scores_synth50k_without_column_scatter(topo, monkeypatch):
+    """The store's IIIB fan-out at the benchmark's synth50k geometry: R and
+    S blocks of 4,096 rows, 13 S blocks of 50,000 rows, lists padded to
+    3,456 (its longest list holds 3,330 rows, bucketed by 128).  The scan
+    scores each S block with a dense product: no scatter moves a (|Br|, M)
+    product, and the program fits one 16 GB chip."""
+    mesh = Mesh(np.array(topo.devices[:1]), ("shard",))
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    rb = sb = 4096
+    b, m, tile = 13, 3456, SYNTHETIC.tile
+    fn = fanout_program("iiib", mesh, ("shard",), rb=rb, k=SYNTHETIC.k,
+                        dim=SYNTHETIC.dim, s_block=sb, tile=tile)
+    rep, shard = NamedSharding(mesh, P()), NamedSharding(mesh, P("shard"))
+
+    def arg(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    args = (
+        arg((T, rb, tile), jnp.float32, rep),
+        arg((T,), jnp.float32, rep),
+        arg((rb,), jnp.bool_, rep),
+        arg((1, b, T + 1, m), jnp.int32, shard),
+        arg((1, b, T + 1, m, tile), jnp.float32, shard),
+        arg((1, b, T + 1), jnp.int32, shard),
+        arg((1, b, sb, T), jnp.float32, shard),
+        arg((1, b, sb), jnp.int32, shard),
+        arg((1, b, sb), jnp.bool_, shard),
+    )
+    compiled = fn.lower(*args).compile()
+    sizes = _scatter_update_sizes(compiled.as_text())
+    assert sizes, "the S densify scatter is missing"
+    assert rb * m not in sizes
+    mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
