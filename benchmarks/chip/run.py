@@ -46,7 +46,7 @@ import layout  # noqa: E402
 import loops  # noqa: E402
 import oracle  # noqa: E402
 import roofline  # noqa: E402
-import tracereduce  # noqa: E402
+import timeline  # noqa: E402
 
 CACHE_DIR = ROOT / ".jax_cache"
 
@@ -81,6 +81,27 @@ def _mem(dev, key):
     return int(stats.get(key, 0))
 
 
+def in_use(devs) -> int:
+    """Device bytes in use, summed over every chip of the cell."""
+    return sum(_mem(d, "bytes_in_use") for d in devs)
+
+
+def read_trace(log_dir: str) -> dict:
+    """The traced window reduced by the program's own names
+    (``timeline.py``): ``tracereduce``'s numbers plus scopes and spans."""
+    return timeline.reduce(timeline.extract(log_dir))
+
+
+def per_layer(bench: dict, cell_name: str, run: dict) -> dict:
+    """The cell's per-layer metrics its readers find in ``run``."""
+    out = {}
+    for m in layout.metrics_of(bench, "per_layer", cell_name):
+        v = layout.reader(m["name"])(run)
+        if v is not None:
+            out[m["name"]] = {"value": v, "unit": m["unit"]}
+    return out
+
+
 def _check_rows(rec, limit, rng):
     """A seeded sample of the rows answered in the window."""
     ids = np.concatenate([a[1] for a in rec["answers"]])
@@ -109,7 +130,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     dim, k = cfg["dim"], cfg["k"]
     s_rows = datagen.generate(cfg, cfg["n_s"], seed, part=0)
     pool = datagen.generate(cfg, cfg["n_r"], seed, part=1)
-    mem0 = _mem(devs[0], "bytes_in_use")
+    mem0 = in_use(devs)
     store = ShardedKNNStore.build(
         SparseBatch(indices=s_rows[0], values=s_rows[1], nnz=s_rows[2], dim=dim),
         JoinSpec(k=k, algorithm=cfg["algorithm"]), num_shards=cfg["shards"])
@@ -118,14 +139,14 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     s_nnz = int(s_rows[2].sum())
 
     def on_ready():
-        return {"t": time.perf_counter(), "bytes": _mem(devs[0], "bytes_in_use")}
+        return {"t": time.perf_counter(), "bytes": in_use(devs)}
 
     log_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     try:
         rec = loops.LOOPS[traffic["loop"]](store, pool, dim, traffic, seconds,
                                            log_dir, counter, on_ready)
         peak = max(_mem(d, "peak_bytes_in_use") for d in devs)
-        reduced = tracereduce.reduce(tracereduce.extract(log_dir)) if trace else None
+        reduced = read_trace(log_dir) if trace else None
     finally:
         if log_dir is not None:
             shutil.rmtree(log_dir, ignore_errors=True)
@@ -173,10 +194,7 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
             "scan": {"ops": ops, "bytes": nbytes,
                      "peak": roofline.peaks(devs[0].device_kind)},
         }
-        for m in layout.metrics_of(bench, "per_layer", cell_name):
-            v = layout.reader(m["name"])(run)
-            if v is not None:
-                result["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
+        result["metrics"] = per_layer(bench, cell_name, run)
         result["device"]["busy_s"] = reduced["busy_s"]
         result["device"]["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"],

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import datagen
 import layout
 import run
 
@@ -45,8 +46,9 @@ def test_without_an_accelerator_it_exits_nonzero_and_prints_no_result():
     assert "no chip" in p.stderr
 
 
-# every configuration on file, the deferred spectra52k too (PERF.md §7)
-CASES = [("synth50k.join", "synth50k"), ("synth50k.join", "spectra52k")]
+# every cell by its own name, and synth50k's loop over the spectra too
+CASES = [("synth50k.join", "synth50k"), ("synth50k.join", "spectra52k"),
+         ("spectra52k.join", "spectra52k")]
 
 
 @pytest.mark.parametrize("cell,config", CASES)
@@ -94,3 +96,29 @@ def _half_left_out(store):
 def test_a_broken_timed_path_is_not_correct(cell, config, fault):
     res = _run(cell, config, fault=fault)
     assert not res["correct"], res["check"]
+
+
+class _StubChip:
+    """A device whose allocator reads ``before`` until the store is built,
+    then ``after``."""
+    platform = device_kind = "stub"
+
+    def __init__(self, before, after):
+        self.readings = [before, after]
+
+    def memory_stats(self):
+        used = self.readings.pop(0) if len(self.readings) > 1 else self.readings[0]
+        return {"bytes_in_use": used, "peak_bytes_in_use": used}
+
+
+def test_index_bytes_count_every_chip_of_the_cell(monkeypatch):
+    """Two chips whose allocators differ: the index is what both gained."""
+    devs = [_StubChip(1_000, 5_000), _StubChip(200, 9_200)]
+    monkeypatch.setattr(run, "chips", lambda n, require_chip: devs)
+    bench, cfg, traffic = _tiny("synth50k.join", "synth50k")
+    res = run.run_cell(bench, "synth50k.join", SEED, 0.2, False, require_chip=False,
+                       cfg=cfg, traffic=traffic)
+    s_nnz = int(datagen.generate(cfg, cfg["n_s"], SEED, part=0)[2].sum())
+    assert res["metrics"]["index_bytes_per_nnz"]["value"] == (4_000 + 9_000) / s_nnz
+    assert res["device"]["count"] == 2
+    assert res["device"]["memory_peak_bytes"] == 9_200

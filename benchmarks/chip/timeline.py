@@ -30,6 +30,8 @@ disagree on the scope gets none.  Nothing is read from the program.
   window.
 - ``ms_per_block``: seconds of the window per 4,096 window rows, the
   fixed yardstick of ``device_ms_per_block.join``.
+- ``named_ms_per_block``: that of a sum of ``scopes`` or ``host_spans``
+  entries, as the per-layer metrics that read the program's names take it.
 """
 from __future__ import annotations
 
@@ -196,3 +198,14 @@ def ms_per_block(seconds: float, rows: int) -> float | None:
     if not rows:
         return None
     return seconds / (rows / ROWS_PER_BLOCK) * 1e3
+
+
+def named_ms_per_block(run: dict, kind: str, names) -> float | None:
+    """The seconds of the ``kind`` (``"scopes"`` or ``"host_spans"``)
+    entries ``names`` of ``run["trace"]``, per 4,096 of ``run["rows"]``, in
+    ms.  A name the trace lacks counts 0 while the trace carries any
+    ``knn.*`` name; a trace that carries none reads ``None``."""
+    t = run["trace"]
+    if not t["host_spans"] and not any(s.startswith("knn.") for s in t["scopes"]):
+        return None
+    return ms_per_block(sum(t[kind].get(n, 0.0) for n in names), run["rows"])
