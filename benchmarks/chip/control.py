@@ -13,11 +13,14 @@ set each limit's upper end.
         --control-seeds 1,2,3 --program-seeds 4,5,...,15
 
 For each seed it makes the cell's data as a run does, takes the query
-rows a run's check would draw from the window (the first calls'
-rows), and prints one JSON line: the control's numbers for a control
-seed; for a program seed the numbers of the program's own answers, from
-``store.query`` on the window's 8,192-row calls.  The benchmark's runs do
-not run this; it needs the chip at the cells' size.
+rows a run's check would draw from the window (in a join cell the first
+calls' rows; in a served cell, whose loop has a ``schedule``, the rows of
+the requests due in a window of ``run_seconds``, each compared over the
+ranks its request asks for), and prints one JSON line: the control's
+numbers for a control seed; for a program seed the numbers of the
+program's own answers, from ``store.query`` on the window's 8,192-row
+calls (a served cell's program readings are its runs' own check).  The
+benchmark's runs do not run this; it needs the chip at the cells' size.
 """
 from __future__ import annotations
 
@@ -30,7 +33,7 @@ import numpy as np
 import run as bench_run  # first: puts the program's src on the path
 import datagen
 import layout
-import loops
+import loopkit
 import oracle
 
 S_BLOCK = 4096
@@ -92,7 +95,18 @@ def control_answers(s_rows, q_rows, dim, k):
     return np.asarray(best_i), np.asarray(best_s)
 
 
-def readings(cfg, traffic, seed, control: bool):
+def _served_rows(schedule, traffic, seconds, n_pool, rng):
+    """The pool rows a served run's check draws, each with its request's
+    k, where every request due in the window is answered."""
+    _, reqs, req_k = schedule(traffic, seconds, n_pool)
+    rows = np.concatenate(reqs)
+    ks = np.repeat(req_k, [len(r) for r in reqs])
+    pos = np.sort(rng.choice(len(rows), min(int(traffic["check_rows"]), len(rows)),
+                             replace=False))
+    return rows[pos], ks[pos]
+
+
+def readings(cfg, traffic, seed, control: bool, seconds: float = 30.0):
     """The compared numbers of one seed: the control's, or the program's
     answers on the window's first calls."""
     dim, k = cfg["dim"], cfg["k"]
@@ -100,7 +114,15 @@ def readings(cfg, traffic, seed, control: bool):
     pool = datagen.generate(cfg, cfg["n_r"], seed, part=1)
     per = int(traffic.get("rows_per_call", 8192))
     rng = np.random.default_rng([seed, 3])
-    r_ix = np.sort(rng.choice(2 * per, int(traffic["check_rows"]), replace=False)) % pool[0].shape[0]
+    loop = layout.module("loops", traffic.get("loop", "closed_join"))
+    schedule = getattr(loop, "schedule", None)
+    if schedule is not None:
+        if not control:
+            raise ValueError("a served cell's program readings are its runs' own check")
+        r_ix, ks = _served_rows(schedule, traffic, seconds, pool[0].shape[0], rng)
+    else:
+        r_ix = np.sort(rng.choice(2 * per, int(traffic["check_rows"]), replace=False)) % pool[0].shape[0]
+        ks = np.full(len(r_ix), k)
     q_rows = tuple(a[r_ix] for a in pool)
     if control:
         ids, scores = control_answers(s_rows, q_rows, dim, k)
@@ -112,7 +134,7 @@ def readings(cfg, traffic, seed, control: bool):
         store = ShardedKNNStore.build(
             SparseBatch(indices=s_rows[0], values=s_rows[1], nnz=s_rows[2], dim=dim),
             JoinSpec(k=k, algorithm=cfg["algorithm"]), num_shards=cfg["shards"])
-        calls = [store.query(loops.rows_batch(pool, np.arange(i * per, (i + 1) * per)
+        calls = [store.query(loopkit.rows_batch(pool, np.arange(i * per, (i + 1) * per)
                                               % pool[0].shape[0], dim))
                  for i in range(2)]
         pos = np.searchsorted(np.arange(2 * per) % pool[0].shape[0], r_ix)
@@ -121,7 +143,9 @@ def readings(cfg, traffic, seed, control: bool):
         del store, calls
     ref = oracle.Reference(oracle.csr64(*s_rows, dim), k)
     ref_scores, _, table = ref.run(*q_rows, dim)
-    return oracle.compare(list(ids), list(scores), ref_scores, table)
+    return oracle.compare([a[:kk] for a, kk in zip(ids, ks)],
+                          [a[:kk] for a, kk in zip(scores, ks)],
+                          [a[:kk] for a, kk in zip(ref_scores, ks)], table)
 
 
 def main(argv=None) -> int:
@@ -138,7 +162,7 @@ def main(argv=None) -> int:
     bench_run.enable_cache()
     for kind, seeds in (("control", args.control_seeds), ("program", args.program_seeds)):
         for seed in (int(s) for s in seeds.split(",") if s):
-            nums = readings(cfg, traffic, seed, kind == "control")
+            nums = readings(cfg, traffic, seed, kind == "control", bench["run_seconds"])
             print(json.dumps({"config": cfg["name"], "kind": kind, "seed": seed,
                               **nums, "limits": cfg["check"],
                               "correct": oracle.verdict(nums, dict(cfg["check"], bad_rows=0))}),

@@ -1,9 +1,25 @@
 """Finds a cell's files by the names in ``BENCHMARK.json``.
 
-A configuration is the file its ``configs`` entry names; a traffic mix is
-``traffic/<mix>.json``; a per-layer metric is ``metrics/<name>.py`` with a
-``read(run)`` function.  Adding a cell, a mix or a metric is adding files
-and entries: nothing here lists them.
+- A cell (``workloads`` entry) names a configuration, a traffic mix and
+  its chips.
+- A configuration is the file its ``configs`` entry names: sizes, the
+  check's limits, and ``data``, whose ``generator`` names a data
+  generator and whose other keys are that generator's parameters.
+- A data generator is ``generators/<name>.py`` with ``generate(cfg, n,
+  structure, seed)``, returning ``n`` rows of the configuration's kind as
+  padded-CSR host arrays (``datagen.py``).
+- A traffic mix is ``traffic/<mix>.json``: parameters only, one of them
+  ``loop``, the loop that drives them.
+- A loop is ``loops/<name>.py`` with ``run(store, pool, dim, traffic,
+  seconds, log_dir, counter, on_ready)``.  It returns a record whose
+  ``metrics`` holds the end-to-end values it measures (``run.py`` adds
+  ``setup_s`` and ``index_bytes_per_nnz``) and whose ``counters``, if
+  any, traced runs hand to the per-layer readers as ``run["loop"]``.
+- A per-layer metric is ``metrics/<name>.py`` with ``read(run)``,
+  returning ``None`` where the run holds nothing to read.
+
+Adding a cell, a configuration, a generator, a mix, a loop or a metric is
+adding files and entries: nothing here lists them.
 """
 from __future__ import annotations
 
@@ -43,11 +59,28 @@ def metrics_of(bench: dict, kind: str, cell_name: str) -> list:
             if "workloads" not in m or cell_name in m["workloads"]]
 
 
-def reader(name: str, here: pathlib.Path = HERE):
-    """``read`` of ``metrics/<name>.py``."""
-    path = here / "metrics" / f"{name}.py"
+def module(kind: str, name: str, here: pathlib.Path = HERE):
+    """The module ``<kind>/<name>.py``."""
+    path = here / kind / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no {kind} file {name!r} under {here}")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str, here: pathlib.Path = HERE):
+    """``read`` of ``metrics/<name>.py``."""
+    return module("metrics", name, here).read
+
+
+def loop(name: str, here: pathlib.Path = HERE):
+    """``run`` of ``loops/<name>.py``."""
+    return module("loops", name, here).run
+
+
+def generator(name: str, here: pathlib.Path = HERE):
+    """``generate`` of ``generators/<name>.py``."""
+    return module("generators", name, here).generate

@@ -1,0 +1,3 @@
+"""``servereads.device_ms_per_batch`` in the cells where it moves
+``served_rows_per_s``."""
+from servereads import device_ms_per_batch as read  # noqa: F401

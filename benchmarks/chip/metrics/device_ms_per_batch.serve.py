@@ -1,0 +1,3 @@
+"""``servereads.device_ms_per_batch`` in the cells where it moves
+``p50_ms``."""
+from servereads import device_ms_per_batch as read  # noqa: F401

@@ -14,7 +14,11 @@ Two numbers are compared, each against its limit:
   the program returned there: a tie returned in another order reads 0, a
   wrong neighbour the distance to the right one.
 - ``bad_rows``, limit 0: rows with an id outside S (a row left without
-  an answer reads -1) or an id twice.
+  an answer reads -1), an id twice, or another number of ranks than its
+  request asked for.
+
+Each row is compared over the ranks its request asked for: the first k
+of the reference's top k, where a served request asks for fewer.
 
 Only ranks whose reference score is above 0 are compared: a row that
 overlaps fewer than k stored rows has no defined neighbour beyond them.
@@ -73,23 +77,27 @@ class Reference:
 def compare(prog_ids, prog_scores, ref_scores, table) -> dict:
     """Compare the program's answers row by row with the reference.
 
-    ``prog_ids[i]`` / ``prog_scores[i]`` hold row i's answer over its k
-    ranks.  Returns the two numbers of the module docstring."""
+    ``prog_ids[i]`` / ``prog_scores[i]`` hold row i's answer and
+    ``ref_scores[i]`` the reference's best scores over the ranks row i
+    asked for.  Returns the two numbers of the module docstring."""
     gap = 0.0
     bad = 0
     n_s = table.shape[1]
     for i, (ids, scores) in enumerate(zip(prog_ids, prog_scores)):
         ids = np.asarray(ids).astype(np.int64).ravel()
         scores = np.asarray(scores, np.float64).ravel()
-        ref = ref_scores[i]
+        ref = np.asarray(ref_scores[i])
         live = ref > 0
         if not live.any():
+            continue
+        if ids.size != ref.size or scores.size != ref.size:
+            bad += 1
             continue
         lid = ids[live]
         if (lid < 0).any() or (lid >= n_s).any() or len(np.unique(lid)) < lid.size:
             bad += 1
             continue
-        scale = ref_scores[i, 0]
+        scale = ref[0]
         gap = max(gap, float(np.max(np.abs(scores[live] - ref[live]))) / scale,
                   float(np.max(np.abs(table[i, lid] - ref[live]))) / scale)
     return {"gap": gap, "bad_rows": bad}

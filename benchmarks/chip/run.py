@@ -6,10 +6,11 @@
 
 The cell, its configuration and its traffic mix are found by name from
 ``BENCHMARK.json`` (see ``layout.py``).  Set-up makes S and the query pool
-from ``--seed``, builds ``ShardedKNNStore`` over S and warms up every shape
-of the window; then the mix's loop measures for ``--seconds``.  Afterwards
-a sample of the window's answers, drawn from the seed, is compared with
-the float64 reference (``oracle.py``).
+from ``--seed``, builds ``ShardedKNNStore`` over S; then the loop the mix
+names warms up every shape of the window and measures for ``--seconds``.
+Afterwards a sample of the window's answers, drawn from the seed, is
+compared with the float64 reference (``oracle.py``), each row over the
+ranks its request asked for.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -43,7 +44,7 @@ import numpy as np  # noqa: E402
 
 import datagen  # noqa: E402
 import layout  # noqa: E402
-import loops  # noqa: E402
+import loopkit  # noqa: E402
 import oracle  # noqa: E402
 import roofline  # noqa: E402
 import timeline  # noqa: E402
@@ -102,12 +103,25 @@ def per_layer(bench: dict, cell_name: str, run: dict) -> dict:
     return out
 
 
-def _check_rows(rec, limit, rng):
-    """A seeded sample of the rows answered in the window."""
-    ids = np.concatenate([a[1] for a in rec["answers"]])
-    scores = np.concatenate([a[2] for a in rec["answers"]])
-    pos = np.sort(rng.choice(len(ids), min(limit, len(ids)), replace=False))
-    return rec["window_rows"][pos], list(ids[pos]), list(scores[pos])
+def _check_rows(rec, limit, k, rng):
+    """A seeded sample of the rows the loop answered: pool rows, the
+    program's ids and scores of each, and the k its request asked for.
+
+    An answer is ``(pool rows, ids, scores)``, asked at the configuration's
+    ``k``, or ``(pool rows, ids, scores, k)``; a row its answer lacks reads
+    an empty answer."""
+    answers = rec["answers"]
+    ends = np.cumsum([len(a[0]) for a in answers])
+    pos = np.sort(rng.choice(ends[-1], min(limit, ends[-1]), replace=False))
+    rows, ids, scores, ks = [], [], [], []
+    for p in pos:
+        a = int(np.searchsorted(ends, p, side="right"))
+        got, j = answers[a], p - (ends[a] - len(answers[a][0]))
+        rows.append(got[0][j])
+        ids.append(got[1][j] if j < len(got[1]) else np.empty(0))
+        scores.append(got[2][j] if j < len(got[2]) else np.empty(0))
+        ks.append(got[3] if len(got) > 3 else k)
+    return np.asarray(rows), ids, scores, ks
 
 
 def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
@@ -115,13 +129,14 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
              traffic=None, t_start: float = T_PROCESS, fault=None) -> dict:
     """One run of one cell; returns the result object.  ``cfg`` and
     ``traffic`` replace the files the cell names, and ``fault`` is called
-    with the built store before the window; tests use them."""
+    with the built store once set-up ends, before the window; tests use
+    them."""
     w = layout.cell(bench, cell_name)
     cfg = cfg or layout.config(bench, w["config"])
     traffic = traffic or layout.traffic(w["traffic"])
     devs = chips(w["chips"], require_chip)
     enable_cache()
-    counter = loops.CompileCounter()
+    counter = loopkit.CompileCounter()
 
     from repro.core import JoinSpec
     from repro.sparse.format import SparseBatch
@@ -134,16 +149,17 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
     store = ShardedKNNStore.build(
         SparseBatch(indices=s_rows[0], values=s_rows[1], nnz=s_rows[2], dim=dim),
         JoinSpec(k=k, algorithm=cfg["algorithm"]), num_shards=cfg["shards"])
-    if fault is not None:
-        fault(store)
     s_nnz = int(s_rows[2].sum())
 
     def on_ready():
-        return {"t": time.perf_counter(), "bytes": in_use(devs)}
+        ready = {"t": time.perf_counter(), "bytes": in_use(devs)}
+        if fault is not None:
+            fault(store)
+        return ready
 
     log_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     try:
-        rec = loops.LOOPS[traffic["loop"]](store, pool, dim, traffic, seconds,
+        rec = layout.loop(traffic["loop"])(store, pool, dim, traffic, seconds,
                                            log_dir, counter, on_ready)
         peak = max(_mem(d, "peak_bytes_in_use") for d in devs)
         reduced = read_trace(log_dir) if trace else None
@@ -156,16 +172,17 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
 
     values = {
         "setup_s": rec["ready"]["t"] - t_start,
-        "rows_per_s": rec["rows_per_s"],
         "index_bytes_per_nnz": (rec["ready"]["bytes"] - mem0) / s_nnz,
+        **rec["metrics"],
     }
 
     # the check: reference on the host, after the window and the store
     rng = np.random.default_rng([seed, 3])
-    r_ix, prog_ids, prog_scores = _check_rows(rec, int(traffic["check_rows"]), rng)
+    r_ix, prog_ids, prog_scores, ks = _check_rows(rec, int(traffic["check_rows"]), k, rng)
     ref = oracle.Reference(oracle.csr64(*s_rows, dim), k)
     ref_scores, _, table = ref.run(pool[0][r_ix], pool[1][r_ix], pool[2][r_ix], dim)
-    numbers = oracle.compare(prog_ids, prog_scores, ref_scores, table)
+    asked = [ref_scores[i, :kk] for i, kk in enumerate(ks)]
+    numbers = oracle.compare(prog_ids, prog_scores, asked, table)
     limits = dict(cfg["check"], bad_rows=0)
     correct = oracle.verdict(numbers, limits)
 
@@ -191,6 +208,8 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
         run = {
             "trace": reduced,
             "rows": len(rows),
+            "loop": rec.get("counters"),
+            "values": values,
             "scan": {"ops": ops, "bytes": nbytes,
                      "peak": roofline.peaks(devs[0].device_kind)},
         }
@@ -206,6 +225,8 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
         "warmup_calls": rec["warmup_calls"],
         "checked_rows": len(r_ix),
         "blocks": rec["blocks"],
+        "counters": rec.get("counters"),
+        **rec.get("info", {}),
         **values,
     }
     result["check"] = {name: {"value": numbers[name], "limit": limits[name]}

@@ -19,3 +19,17 @@ def test_control_fails_and_program_passes(name):
     prog = control.readings(cfg, traffic, 11, control=False)
     assert not control.oracle.verdict(ctl, limits), ctl
     assert control.oracle.verdict(prog, limits), prog
+
+
+@pytest.mark.parametrize("mix", ["serve", "overload"])
+def test_control_fails_on_the_rows_a_served_check_draws(mix):
+    """Each row compared over the ranks its request asked for; the
+    program's side of a served cell is its runs' own check."""
+    cfg = json.loads((layout.HERE / "configs" / "synth50k.json").read_text())
+    cfg.update(n_s=3000, n_r=3000)
+    traffic = layout.traffic(mix)
+    limits = dict(cfg["check"], bad_rows=0)
+    ctl = control.readings(cfg, traffic, 11, control=True, seconds=30)
+    assert not control.oracle.verdict(ctl, limits), ctl
+    with pytest.raises(ValueError):
+        control.readings(cfg, traffic, 11, control=False, seconds=30)

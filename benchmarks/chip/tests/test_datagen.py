@@ -1,7 +1,11 @@
+import hashlib
+import json
+
 import numpy as np
 import pytest
 
 import datagen
+import layout
 
 SYN = {"dim": 10_000, "data": {"generator": "synthetic", "structure_seed": 5,
                                "nnz_mean": 120, "nnz_std": 30, "weight_low": 0.001, "weight_high": 1.0}}
@@ -62,3 +66,21 @@ def test_spectra_rows_peak_at_one():
     idx, val, nnz = datagen.generate(SPEC, 2000, 5, part=0)
     np.testing.assert_allclose(val.max(axis=1), 1.0)
     assert 60 < nnz.mean() < 85, "peaks merge only where they land on one dim"
+
+
+# sha256 of idx, val and nnz of each batch at the configurations' sizes,
+# seed 3000000001, as the generators gave them before they became files
+PINNED = {
+    ("synth50k", 0): "41e84140f27c7a62b13d2576d6ac1208ad60756c80a4fdf62743eccc3d77527b",
+    ("synth50k", 1): "41368945ff052f27892c3c8eb441450ac4857f985aeecbace829a6531e8cd0d7",
+    ("spectra52k", 0): "2e299e7159bd0c3bd4400e0008493c5a401b95a177b811fde30cf9cfdfc34f60",
+    ("spectra52k", 1): "3c1e61cda3cfaed1f73c93aad1204b4c9876d37a5029f89d92d2d90466ea857f",
+}
+
+
+@pytest.mark.parametrize("name,part", sorted(PINNED))
+def test_the_generators_give_the_same_arrays_bit_for_bit(name, part):
+    cfg = json.loads((layout.HERE / "configs" / f"{name}.json").read_text())
+    rows = datagen.generate(cfg, cfg["n_s"] if part == 0 else cfg["n_r"], 3000000001, part)
+    digest = hashlib.sha256(b"".join(a.tobytes() for a in rows)).hexdigest()
+    assert digest == PINNED[name, part]

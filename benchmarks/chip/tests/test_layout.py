@@ -1,7 +1,6 @@
 import json
 
 import layout
-import loops
 
 
 def test_a_new_config_mix_and_metric_are_found_by_name(tmp_path):
@@ -35,6 +34,6 @@ def test_every_cell_of_the_benchmark_resolves():
     for w in bench["workloads"]:
         cfg = layout.config(bench, w["config"])
         assert cfg["name"] == w["config"]
-        assert layout.traffic(w["traffic"])["loop"] in loops.LOOPS
+        assert callable(layout.loop(layout.traffic(w["traffic"])["loop"]))
         for m in layout.metrics_of(bench, "per_layer", w["name"]):
             assert callable(layout.reader(m["name"]))

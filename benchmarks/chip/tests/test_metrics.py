@@ -14,6 +14,7 @@ import tracereduce
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 PLAIN = DATA / "trace_synth50k_join.json"
 SCOPED = DATA / "trace_synth50k_join_scoped.json"
+SERVED = DATA / "trace_synth50k_serve.json"
 ROWS = 3 * 4096
 NAMED = {
     "matmul_ms_per_block.join": ("scopes", ["knn.matmul"]),
@@ -24,8 +25,18 @@ NAMED = {
 }
 
 
-def _run(trace):
-    return {"trace": trace, "rows": ROWS,
+# the scheduler's window counters a served run hands its readers
+BATCHES = 4
+COUNTERS = {"batches": BATCHES, "requests": 5, "live_rows": 11,
+            "padded_rows": BATCHES * 256}
+
+
+# the end-to-end values a served run measured, handed to its readers
+VALUES = {"p50_ms": 240.0, "p95_ms": 610.0, "setup_s": 35.0}
+
+
+def _run(trace, loop=None, values=None):
+    return {"trace": trace, "rows": ROWS, "loop": loop, "values": values,
             "scan": {"ops": 3e12, "bytes": 2e9, "peak": roofline.peaks("TPU v5 lite")}}
 
 
@@ -84,12 +95,44 @@ def test_a_name_the_trace_lacks_reads_zero_and_a_trace_without_names_none(monkey
         assert layout.reader(name)(_run(unnamed)) is None
 
 
+def _clipped_ns(host, names, lo, hi):
+    return sum(min(s + d, hi) - max(s, lo) for s, d, n in host
+               if n in names and s < hi and s + d > lo)
+
+
+@pytest.mark.parametrize("suffix", ["serve", "overload"])
+def test_served_readers_give_the_traces_own_sums_per_batch(monkeypatch, suffix):
+    """On a recorded window of ``synth50k.serve`` (v5e): each batch's
+    ``knn.batch`` span holds its ``knn.store.dispatch`` span, and the
+    S densify is the ``knn.scatter`` scope's leaf ops."""
+    ex, named = _read_as_run_does(monkeypatch, SERVED)
+    lo, hi = tracereduce._window(ex["host"])
+    [events] = ex["devices"].values()
+    scatter_ns = sum(e[1] for e in events
+                     if e[3] == "knn.scatter" and e[0] < hi and e[0] + e[1] > lo)
+    wait_ns = (_clipped_ns(ex["host"], {"knn.batch"}, lo, hi)
+               - _clipped_ns(ex["host"], {"knn.store.dispatch"}, lo, hi))
+    want = {"dispatch_wait_ms": wait_ns / 1e6 / BATCHES,
+            "scatter_ms_per_batch": scatter_ns / 1e6 / BATCHES,
+            "device_ms_per_batch": named["busy_s"] * 1e3 / BATCHES,
+            "pad_share": 100.0 * (1 - 11 / (BATCHES * 256)),
+            "requests_per_batch": 5 / BATCHES}
+    assert 40 < want["scatter_ms_per_batch"] < 60 < want["device_ms_per_batch"] < 100
+    assert want["dispatch_wait_ms"] > 0
+    for name, value in want.items():
+        read = layout.reader(f"{name}.{suffix}")
+        assert read(_run(named, COUNTERS)) == pytest.approx(value, rel=1e-12), name
+        assert read(_run(named)) is None, name
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in layout.benchmark()["workloads"]])
 def test_a_traced_run_reports_every_per_layer_metric_of_its_cell(monkeypatch, cell):
-    """What a cell's ``--trace 1`` line must carry, read from a trace that
-    holds the program's names."""
+    """What a cell's ``--trace 1`` line must carry, read from a trace of its
+    kind that holds the program's names; a served cell's run also carries
+    the scheduler's window counters."""
     bench = layout.benchmark()
-    _, named = _read_as_run_does(monkeypatch, SCOPED)
-    got = run.per_layer(bench, cell, _run(named))
+    served = layout.traffic(layout.cell(bench, cell)["traffic"])["loop"] == "open_serve"
+    _, named = _read_as_run_does(monkeypatch, SERVED if served else SCOPED)
+    got = run.per_layer(bench, cell, _run(named, *((COUNTERS, VALUES) if served else ())))
     assert set(got) == {m["name"] for m in layout.metrics_of(bench, "per_layer", cell)}
     assert all(isinstance(m["value"], float) and m["value"] > 0 for m in got.values())

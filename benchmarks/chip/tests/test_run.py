@@ -1,6 +1,7 @@
 """The harness end to end on the CPU at a tiny size, with the chip check
 skipped, and with the timed path broken underneath to see ``correct``
 come out false."""
+import asyncio
 import dataclasses
 import json
 import os
@@ -25,7 +26,9 @@ def _tiny(cell, config):
     cfg = json.loads((layout.HERE / "configs" / f"{config}.json").read_text())
     cfg.update(n_s=500, n_r=600)
     cfg["dim"] = 2000 if cfg["data"]["generator"] == "synthetic" else 4000
-    traffic = dict(layout.traffic(w["traffic"]), rows_per_call=256)
+    traffic = layout.traffic(w["traffic"])
+    if "rows_per_call" in traffic:
+        traffic["rows_per_call"] = 256
     return bench, cfg, traffic
 
 
@@ -48,7 +51,9 @@ def test_without_an_accelerator_it_exits_nonzero_and_prints_no_result():
 
 # every cell by its own name, and synth50k's loop over the spectra too
 CASES = [("synth50k.join", "synth50k"), ("synth50k.join", "spectra52k"),
-         ("spectra52k.join", "spectra52k")]
+         ("spectra52k.join", "spectra52k"), ("synth50k.serve", "synth50k"),
+         ("synth50k.overload", "synth50k")]
+SERVED = [c for c in CASES if c[0].endswith((".serve", ".overload"))]
 
 
 @pytest.mark.parametrize("cell,config", CASES)
@@ -96,6 +101,57 @@ def _half_left_out(store):
 def test_a_broken_timed_path_is_not_correct(cell, config, fault):
     res = _run(cell, config, fault=fault)
     assert not res["correct"], res["check"]
+
+
+def _first_request_altered(store):
+    """The best neighbour of the first row of the window's first store
+    call replaced: one request's answer altered where it is produced."""
+    query = store.query
+    calls = []
+
+    def altered(R, **kw):
+        res = query(R, **kw)
+        calls.append(1)
+        if len(calls) > 1:
+            return res
+        ids = np.asarray(res.ids).copy()
+        ids[0, 0] = (ids[0, 0] + 1) % store.num_vectors
+        return dataclasses.replace(res, ids=jnp.asarray(ids))
+
+    store.query = altered
+
+
+def _two_swapped(monkeypatch):
+    """The answers of the window's first two answered requests handed to
+    each other, as a de-interleave that mixes up two requests would."""
+    from repro.serve import scheduler
+
+    submit = scheduler.KNNScheduler.submit
+    held = []
+
+    async def swapped(self, rows, k=None, **kw):
+        res = await submit(self, rows, k=k, **kw)
+        held.append(res)
+        if len(held) == 1:
+            other = asyncio.get_running_loop().create_future()
+            held.append(other)
+            return await other
+        if len(held) == 3:
+            held[1].set_result(res)
+            return held[0]
+        return res
+
+    return lambda store: monkeypatch.setattr(scheduler.KNNScheduler, "submit", swapped)
+
+
+@pytest.mark.parametrize("fault", ["one_altered", "two_swapped"])
+@pytest.mark.parametrize("cell,config", SERVED)
+def test_a_broken_serving_path_is_not_correct(cell, config, fault, monkeypatch):
+    broken = (_first_request_altered if fault == "one_altered"
+              else _two_swapped(monkeypatch))
+    res = _run(cell, config, fault=broken)
+    assert not res["correct"], res["check"]
+    assert res["attempted"] > 2
 
 
 class _StubChip:
