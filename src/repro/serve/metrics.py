@@ -123,6 +123,8 @@ class ServeMetrics:
         "deadline_misses": ("serve_deadline_misses",
                             "delivered after their deadline"),
         "batches": ("serve_batches", "dispatched batches"),
+        "held_batches": ("serve_batches_held",
+                         "batches whose forming waited for an in-flight batch"),
         "batch_rows": ("serve_batch_rows", "live rows over all batches"),
         "retries": ("serve_batch_retries", "batch dispatch retries"),
         "timeouts": ("serve_batch_timeouts", "batch watchdog firings"),

@@ -24,17 +24,25 @@ model) are wasted.  This is the LLM-serving continuous-batching pattern
   IIIB's batch-global MinPruneScore only moves *work*, not answers
   (Theorem 1 masks provably-safe entries only).
 
-* **Flush policy** — a batch is dispatched when the first of these
-  fires: (1) *block-full*: queued rows ≥ ``r_block``; (2) *window
-  expiry*: the oldest queued request has waited ``window_s``;
-  (3) *deadline pressure*: the nearest request deadline minus the
-  EWMA batch service time (minus ``slack_s``) has arrived.
+* **Flush policy** — a batch is formed only while no batch is in
+  flight (taken and not yet delivered or failed, retries and recovery
+  waits included).  Once the worker is free, it is formed when the
+  first of these fires: (1) *block-full*: queued rows ≥ ``r_block``;
+  (2) *window expiry*: the oldest queued request has waited
+  ``window_s``; (3) *deadline pressure*: the nearest request deadline
+  minus the EWMA batch service time (minus ``slack_s``) has arrived.
+  Requests that arrive while a batch is in flight stay queued and
+  coalesce into the next batch, formed the moment the in-flight one
+  finishes; with one worker that batch reaches the device no later
+  than any batch formed earlier could have.  ``metrics.held_batches``
+  counts the batches whose forming waited so.
 
 * **Dispatch** — one ``store.query()`` per batch, on a single-thread
   executor so the event loop (and therefore ``submit()``) never blocks
   on device work: the flush path takes requests off the queue and
   returns; the queue is open for new submissions while the batch is in
-  flight (tests assert this).  Each dispatch is wrapped in
+  flight (tests assert this), and held requests count toward
+  ``queue_rows_hwm``.  Each dispatch is wrapped in
   ``runtime.fault.with_timeout`` and retried per
   ``runtime.fault.RetryPolicy`` (jittered backoff); exhausted retries
   fail only that batch's futures.
@@ -109,7 +117,8 @@ class ServeConfig:
     ``r_block``     — coalesced batch geometry; defaults to the store's
                       resolved plan.
     ``window_s``    — micro-batch window: max time the oldest request
-                      waits before a partial batch flushes.
+                      waits, once no batch is in flight, before a
+                      partial batch flushes.
     ``queue_rows_hwm`` — admission high-water mark in queued ROWS
                       (requests are variable-sized; rows are the unit the
                       device cost scales with).  Default 64 × r_block.
@@ -219,6 +228,8 @@ class KNNScheduler:
         self._event: Optional[asyncio.Event] = None
         self._flusher: Optional[asyncio.Task] = None
         self._dispatches: set = set()
+        self._in_flight = 0        # batches taken and not yet finished
+        self._held = False         # a due batch is waiting for the worker
         self._exec: Optional[concurrent.futures.ThreadPoolExecutor] = None
         self._recovering: Optional[asyncio.Task] = None
         self._resyncing: Optional[asyncio.Task] = None
@@ -255,6 +266,7 @@ class KNNScheduler:
             self.metrics.queue_depth -= self._queued_rows
             self._pending.clear()
             self._queued_rows = 0
+            self._held = False
         self._event.set()
         await self._flusher
         while self._dispatches:
@@ -376,14 +388,19 @@ class KNNScheduler:
                     continue  # raced with a submit()/stop() before clear()
                 await self._event.wait()
                 continue
-            if self._queued_rows >= self.r_block or not self._running:
-                self._start_batch()
-                continue
             timeout = self._flush_at() - time.monotonic()
-            if timeout <= 0:
+            due = (self._queued_rows >= self.r_block or not self._running
+                   or timeout <= 0)
+            if due and not self._in_flight:
                 self._start_batch()
                 continue
             self._event.clear()
+            if due:
+                # the worker is busy: hold the batch until _batch_done
+                # sets the event; requests keep coalescing meanwhile
+                self._held = True
+                await self._event.wait()
+                continue
             try:
                 await asyncio.wait_for(self._event.wait(), timeout)
             except (asyncio.TimeoutError, TimeoutError):
@@ -407,9 +424,19 @@ class KNNScheduler:
             rows += n
         self._queued_rows -= rows
         self.metrics.on_batch_start(rows)
+        if self._held:
+            self.metrics.held_batches += 1
+            self._held = False
+        self._in_flight += 1
         task = asyncio.create_task(self._dispatch(taken, rows))
         self._dispatches.add(task)
         task.add_done_callback(self._dispatches.discard)
+        task.add_done_callback(self._batch_done)
+
+    def _batch_done(self, _task: asyncio.Task) -> None:
+        """The worker is free: wake the flusher to form the next batch."""
+        self._in_flight -= 1
+        self._event.set()
 
     # -- dispatch ------------------------------------------------------------
 

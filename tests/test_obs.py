@@ -140,6 +140,7 @@ def test_metrics_attributes_are_registry_cells():
     assert parsed["serve_requests_submitted"]["value"] == m.submitted == 1
     assert parsed["serve_batch_retries"]["value"] == m.retries == 1
     assert parsed["serve_batches"]["value"] == 1
+    assert parsed["serve_batches_held"]["value"] == m.held_batches == 0
     assert parsed["serve_inflight"]["value"] == 0     # completed drained it
     assert parsed["serve_inflight_peak"]["value"] == 1
     assert parsed["serve_latency_seconds"]["count"] == 1

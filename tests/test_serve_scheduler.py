@@ -60,6 +60,7 @@ class StubStore:
         self.fail_first = fail_first
         self.calls = 0
         self.batch_rows = []
+        self.live_rows = []                 # non-pad rows of each batch
         self.started = threading.Event()
 
     def query(self, R: SparseBatch):
@@ -70,6 +71,7 @@ class StubStore:
         if self.sleep_s:
             time.sleep(self.sleep_s)
         self.batch_rows.append(R.num_vectors)
+        self.live_rows.append(int((np.asarray(R.nnz) > 0).sum()))
         base = np.asarray(R.nnz)[:, None].astype(np.int32) * 10
         ids = base + np.arange(self.spec.k, dtype=np.int32)[None, :]
         st = JoinStats()
@@ -215,6 +217,142 @@ def test_head_of_line_request_never_splits():
                 sched.submit(tiny_rows(3)), sched.submit(tiny_rows(3)))
         assert store.calls == 2
         assert store.batch_rows == [4, 4]   # 3+pad | 3+pad, never 4|2
+
+    asyncio.run(main())
+
+
+# ---------------------------------------------------------------------------
+# forming waits for the in-flight batch
+# ---------------------------------------------------------------------------
+
+async def _until_set(started: threading.Event):
+    while not started.is_set():           # first batch inside query()
+        await asyncio.sleep(0.001)
+
+
+def test_requests_behind_inflight_batch_coalesce():
+    """Requests that arrive while batch A is inside store.query() are held,
+    not formed one per window: they leave as ONE batch B once A is done."""
+    store = StubStore(sleep_s=0.3)
+
+    async def main():
+        cfg = ServeConfig(r_block=16, window_s=0.001)
+        async with KNNScheduler(store, cfg) as sched:
+            a = asyncio.create_task(sched.submit(tiny_rows(2)))
+            await _until_set(store.started)
+            held = []
+            for _ in range(5):                # each one's window expires
+                held.append(asyncio.create_task(sched.submit(tiny_rows(1))))
+                await asyncio.sleep(0.01)
+            assert sched.metrics.queue_depth == 5     # still queued
+            await asyncio.gather(a, *held)
+        assert store.calls == 2
+        assert store.live_rows == [2, 5]
+        assert sched.metrics.held_batches == 1
+        assert sched.metrics.completed == 6
+
+    asyncio.run(main())
+
+
+def test_held_rows_count_toward_high_water_mark():
+    """Rows held behind an in-flight batch stay queued, so admission
+    control sees them: a submit past queue_rows_hwm bounces, and its
+    retry succeeds once the held rows drain."""
+    store = StubStore(sleep_s=0.3)
+
+    async def main():
+        cfg = ServeConfig(r_block=4, window_s=0.001, queue_rows_hwm=6)
+        async with KNNScheduler(store, cfg) as sched:
+            a = asyncio.create_task(sched.submit(tiny_rows(4)))
+            await _until_set(store.started)
+            held = []
+            for _ in range(3):
+                held.append(asyncio.create_task(sched.submit(tiny_rows(1))))
+                await asyncio.sleep(0.01)
+            with pytest.raises(QueueFull) as exc:
+                await sched.submit(tiny_rows(4))  # 3 held + 4 > hwm=6
+            assert exc.value.retry_after_s > 0
+            await asyncio.gather(a, *held)
+            await sched.submit(tiny_rows(4))      # the retry is admitted
+        assert sched.metrics.rejected == 1
+        assert sched.metrics.completed == 5
+        assert store.live_rows == [4, 3, 4]
+
+    asyncio.run(main())
+
+
+def test_stop_with_drain_completes_held_requests():
+    """stop(drain=True) while requests are held behind an in-flight batch
+    forms them into a batch once the worker frees and completes all."""
+    store = StubStore(sleep_s=0.2)
+
+    async def main():
+        cfg = ServeConfig(r_block=8, window_s=0.001)
+        sched = await KNNScheduler(store, cfg).start()
+        a = asyncio.create_task(sched.submit(tiny_rows(2)))
+        await _until_set(store.started)
+        held = [asyncio.create_task(sched.submit(tiny_rows(n)))
+                for n in (1, 2, 3)]
+        await asyncio.sleep(0.02)
+        assert sched.metrics.queue_depth == 6
+        await sched.stop(drain=True)
+        for t, n in zip([a, *held], (2, 1, 2, 3)):
+            assert t.done()
+            ids, _ = t.result()
+            assert ids.shape == (n, 4)
+        assert store.live_rows == [2, 6]
+        assert sched.metrics.completed == 4
+        assert sched.metrics.failed == 0
+        assert sched.metrics.held_batches == 1
+        assert sched.metrics.queue_depth == 0
+        assert sched.metrics.inflight == 0
+
+    asyncio.run(main())
+
+
+@pytest.mark.parametrize("algorithm", ["iib", "iiib"])
+def test_held_batch_parity_ragged_sizes_and_k(algorithm):
+    """Batches formed behind an in-flight batch coalesce ragged requests
+    of differing k; each answer is bit-identical to a direct store.query()
+    of the same rows."""
+    S = synthetic_sparse(96, dim=256, nnz_mean=12, seed=1)
+    R = synthetic_sparse(36, dim=256, nnz_mean=10, seed=2)
+    store = ShardedKNNStore.build(
+        S, JoinSpec(k=5, algorithm=algorithm, r_block=8, s_block=32))
+    direct = store.query
+    started = threading.Event()
+
+    def slow_query(batch, **kw):            # keeps each batch in flight
+        started.set()
+        time.sleep(0.05)
+        return direct(batch, **kw)
+
+    store.query = slow_query
+    sizes = [2, 1, 3, 2, 5, 4, 1, 2, 6]
+    ks = [5, 5, 2, 4, 5, 1, 3, 5, 2]
+    bounds = np.concatenate([[0], np.cumsum(sizes)])
+    reqs = [(int(bounds[i]), int(bounds[i + 1]), ks[i])
+            for i in range(len(sizes))]
+
+    async def main():
+        async with KNNScheduler(
+            store, ServeConfig(r_block=8, window_s=0.001)
+        ) as sched:
+            (lo, hi, k), rest = reqs[0], reqs[1:]
+            first = asyncio.create_task(sched.submit(rows_of(R, lo, hi), k=k))
+            await _until_set(started)
+            outs = await asyncio.gather(first, *[
+                sched.submit(rows_of(R, lo, hi), k=k) for lo, hi, k in rest])
+        for (ids, scores), (lo, hi, k) in zip(outs, reqs):
+            want = direct(rows_of(R, lo, hi))
+            assert ids.shape == (hi - lo, k)
+            np.testing.assert_array_equal(ids, np.asarray(want.ids)[:, :k])
+            np.testing.assert_array_equal(
+                scores, np.asarray(want.scores)[:, :k])
+        m = sched.metrics
+        assert m.held_batches >= 1
+        assert m.batches < len(reqs)         # held requests coalesced
+        assert m.completed == len(reqs)
 
     asyncio.run(main())
 
