@@ -1,137 +1,13 @@
-"""§Roofline — three-term roofline per (arch × shape × mesh) from the
-dry-run artifacts (experiments/dryrun/*.json).
+"""Join-planner calibration: measure the C2/C3 unit costs of the dense
+(BF) and indexed (IIB) scans on this device and write the record that
+``core.engine.plan(calibration=...)`` reads.
 
-Terms (TPU v5e constants):
-    compute    = FLOPs_per_chip / 197e12        [bf16 peak]
-    memory     = HBM_bytes_per_chip / 819e9
-    collective = collective_bytes_per_chip / 50e9  [per-link ICI]
-
-FLOPs/bytes come from the trip-count-aware HLO analysis (launch/
-hlo_analysis.py) — ``cost_analysis`` counts scan bodies once and is
-reported alongside for reference.  MODEL_FLOPS = 6·N_active·D_tokens
-(trains; 3 passes) or 2·N_active·D_tokens (inference fwd) + attention
-cache reads; the ratio MODEL/HLO exposes remat & dispatch waste.
+  PYTHONPATH=src python -m benchmarks.roofline --calibrate out.json [--full]
 """
 from __future__ import annotations
 
-import glob
 import json
-import os
-from typing import Dict, List, Optional
-
-from repro.configs.base import get_config
-from repro.launch.shapes import SHAPES
-
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
-LINK_BW = 50e9           # bytes/s / link (ICI)
-
-DRYRUN_DIR = os.environ.get("DRYRUN_DIR", "experiments/dryrun")
-
-
-def model_flops(arch: str, shape_name: str) -> float:
-    """Analytic 'useful' FLOPs per step (global, all chips)."""
-    cfg = get_config(arch)
-    shape = SHAPES[shape_name]
-    n_active = cfg.active_param_count()
-    b, s = shape.global_batch, shape.seq_len
-    d_attn = cfg.num_heads * cfg.resolved_head_dim
-    if shape.kind == "train":
-        tokens = b * s
-        # 6ND + attention score/value matmuls fwd+bwd (12·L·S·d_attn per tok)
-        return 6.0 * n_active * tokens + 12.0 * cfg.num_layers * s * d_attn * tokens / 2
-    if shape.kind == "prefill":
-        tokens = b * s
-        return 2.0 * n_active * tokens + 4.0 * cfg.num_layers * s * d_attn * tokens / 2
-    # decode: one token per sequence + attention over the cache
-    if cfg.family == "ssm":
-        ctx = 1  # O(1) recurrent state, no cache scan
-    elif cfg.family == "hybrid":
-        # only the attention layers (1 in |pattern|) scan a window
-        frac_attn = (
-            sum(1 for p in cfg.block_pattern if p != "rglru")
-            / max(len(cfg.block_pattern), 1)
-        )
-        ctx = max(int(frac_attn * min(s, cfg.local_window or s)), 1)
-    else:
-        ctx = s
-    return 2.0 * n_active * b + 4.0 * cfg.num_layers * ctx * d_attn * b
-
-
-def load_cells(pattern: str = "*") -> List[Dict]:
-    cells = []
-    for path in sorted(glob.glob(os.path.join(DRYRUN_DIR, pattern + ".json"))):
-        with open(path) as f:
-            cells.append(json.load(f))
-    return cells
-
-
-def roofline_row(rec: Dict) -> Optional[Dict]:
-    if "skipped" in rec or "error" in rec:
-        return None
-    ha = rec.get("hlo_analysis")
-    if not ha:
-        return None
-    chips = rec.get("n_chips", 256)
-    flops = ha["flops_per_chip"]
-    hbm = ha["hbm_bytes_per_chip"]
-    coll = ha["collective_bytes_per_chip"]
-    t_c = flops / PEAK_FLOPS
-    t_m = hbm / HBM_BW
-    t_x = coll / LINK_BW
-    dom = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))[1]
-    mf = model_flops(rec["arch"], rec["shape"])
-    bound = max(t_c, t_m, t_x)
-    return {
-        "arch": rec["arch"],
-        "shape": rec["shape"],
-        "mesh": rec["mesh"],
-        "compute_s": t_c,
-        "memory_s": t_m,
-        "collective_s": t_x,
-        "dominant": dom,
-        "model_flops": mf,
-        "hlo_flops": flops * chips,
-        "useful_ratio": mf / max(flops * chips, 1.0),
-        "roofline_fraction": t_c / max(bound, 1e-12),
-        "step_bound_s": bound,
-        "temp_gib": rec.get("memory_analysis", {}).get("temp_size_in_bytes", 0) / 2**30,
-        "args_gib": rec.get("memory_analysis", {}).get("argument_size_in_bytes", 0) / 2**30,
-    }
-
-
-def build_table(mesh: str = "16x16", tag: str = "") -> List[Dict]:
-    rows = []
-    for rec in load_cells(f"*_{mesh}{tag}"):
-        row = roofline_row(rec)
-        if row:
-            rows.append(row)
-        elif "skipped" in rec:
-            rows.append({
-                "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
-                "dominant": "SKIP", "note": rec["skipped"][:40],
-            })
-    return rows
-
-
-def fmt_table(rows: List[Dict]) -> str:
-    hdr = (
-        f"{'arch':26s} {'shape':12s} {'compute_s':>10s} {'memory_s':>10s} "
-        f"{'collect_s':>10s} {'dominant':>10s} {'useful%':>8s} {'roofl%':>7s} "
-        f"{'temp GiB':>9s}"
-    )
-    lines = [hdr, "-" * len(hdr)]
-    for r in rows:
-        if r.get("dominant") == "SKIP":
-            lines.append(f"{r['arch']:26s} {r['shape']:12s} {'— skipped (' + r.get('note','')[:38] + ')'}")
-            continue
-        lines.append(
-            f"{r['arch']:26s} {r['shape']:12s} {r['compute_s']:10.3f} "
-            f"{r['memory_s']:10.3f} {r['collective_s']:10.3f} {r['dominant']:>10s} "
-            f"{100*r['useful_ratio']:8.1f} {100*r['roofline_fraction']:7.1f} "
-            f"{r['temp_gib']:9.2f}"
-        )
-    return "\n".join(lines)
+from typing import Dict
 
 
 def calibrate(out_path: str, fast: bool = True) -> Dict:
@@ -146,8 +22,6 @@ def calibrate(out_path: str, fast: bool = True) -> Dict:
     ``plan(..., calibration=...)`` turns its scores into wall-second
     estimates with the machine's true dense/indexed throughput ratio.
     """
-    import json as _json
-
     import jax
 
     from benchmarks.common import gen, timed
@@ -185,36 +59,19 @@ def calibrate(out_path: str, fast: bool = True) -> Dict:
         },
     }
     with open(out_path, "w") as f:
-        _json.dump(record, f, indent=1)
+        json.dump(record, f, indent=1)
         f.write("\n")
     print(f"calibration (index_cost_factor={c3 / c2:.2f}) -> {out_path}")
     return record
-
-
-def run(fast: bool = False):
-    out = {}
-    for mesh in ("16x16", "pod2x16x16"):
-        rows = build_table(mesh)
-        if rows:
-            print(f"\n== Roofline ({mesh}) ==")
-            print(fmt_table(rows), flush=True)
-            out[mesh] = rows
-    from benchmarks.common import save_result
-
-    save_result("roofline", out)
-    return out
 
 
 if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--calibrate", metavar="OUT.json", default=None,
+    ap.add_argument("--calibrate", metavar="OUT.json", required=True,
                     help="measure C2/C3 unit costs for plan(calibration=...)")
     ap.add_argument("--full", action="store_true",
                     help="calibrate on the full (slower) shape")
     args = ap.parse_args()
-    if args.calibrate:
-        calibrate(args.calibrate, fast=not args.full)
-    else:
-        run()
+    calibrate(args.calibrate, fast=not args.full)

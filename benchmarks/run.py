@@ -1,4 +1,4 @@
-"""Benchmark harness — one module per paper figure + the roofline table.
+"""Benchmark harness — one module per paper figure.
 
   PYTHONPATH=src python -m benchmarks.run            # full
   PYTHONPATH=src python -m benchmarks.run --fast     # CI-sized
@@ -15,7 +15,7 @@ import platform
 import sys
 import time
 
-from benchmarks import fig1_data_size, fig2_relative_size, fig3_effect_k, fig4_buffer_size, roofline
+from benchmarks import fig1_data_size, fig2_relative_size, fig3_effect_k, fig4_buffer_size
 from repro.runtime.compile_cache import enable_compile_cache
 
 SUITES = {
@@ -23,7 +23,6 @@ SUITES = {
     "fig2_relative_size": fig2_relative_size.run,
     "fig3_effect_k": fig3_effect_k.run,
     "fig4_buffer_size": fig4_buffer_size.run,
-    "roofline": roofline.run,
 }
 
 

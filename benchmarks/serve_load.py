@@ -406,7 +406,7 @@ def run_replica_faulted(n_requests: int, rate: float, n_store: int, dim: int,
     import jax
 
     from repro.core.engine import SparseKNNIndex
-    from repro.launch.mesh import make_store_mesh
+    from repro.store.mesh import make_store_mesh
     from repro.runtime.fault import FaultPlan, FaultSpec
 
     if jax.device_count() < 4:

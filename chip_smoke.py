@@ -349,7 +349,7 @@ def main(argv=None) -> int:
         reference_s=time.perf_counter() - t0, tol=TOL)
 
     if args.chips == 4:
-        from repro.launch.mesh import make_store_mesh
+        from repro.store.mesh import make_store_mesh
 
         layouts = {"shards4": make_store_mesh(4),
                    "shards2x2": make_store_mesh(2, replicas=2)}

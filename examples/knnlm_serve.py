@@ -7,6 +7,10 @@ next tokens re-weight the LM distribution:
 
     p(y) = (1 - lam) * p_LM(y) + lam * softmax_knn(y)
 
+The language model is only the query producer, so a seeded numpy stand-in
+plays it: a fixed random token-embedding table and a fixed random
+recurrence give the "hidden states" and the next-token logits.
+
 This is the showcase for the serving stack (DESIGN.md §7 + §8):
 
 * the datastore lives in a :class:`ShardedKNNStore` — indexes built once
@@ -38,13 +42,8 @@ import asyncio
 import time
 
 import numpy as np
-import jax
-import jax.numpy as jnp
 
-from repro.configs.base import get_config
 from repro.core import JoinSpec
-from repro.launch.serve import Request, Server
-from repro.models import model as M
 from repro.obs import FlightRecorder, ProfileCapture
 from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import KNNScheduler, ServeConfig
@@ -64,18 +63,42 @@ def sparsify(h: np.ndarray, keep: int = 32) -> SparseBatch:
     )
 
 
+class ToyLM:
+    """Seeded stand-in decoder: ``h' = tanh(h W + E[tok])`` over a fixed
+    random embedding table ``E`` and recurrence ``W``; next-token logits
+    are ``h E^T`` (tied embeddings)."""
+
+    def __init__(self, rng, vocab_size: int = 256, d_model: int = 64):
+        self.vocab_size = vocab_size
+        scale = 1.0 / np.sqrt(d_model)
+        self.emb = (scale * rng.standard_normal((vocab_size, d_model))).astype(np.float32)
+        self.w = (scale * rng.standard_normal((d_model, d_model))).astype(np.float32)
+
+    def step(self, h: np.ndarray, tokens: np.ndarray) -> np.ndarray:
+        return np.tanh(h @ self.w + self.emb[tokens])
+
+    def hidden_states(self, tokens: np.ndarray) -> np.ndarray:
+        """Final hidden state of each row of ``tokens`` (n, t) -> (n, d)."""
+        h = np.zeros((tokens.shape[0], self.w.shape[0]), np.float32)
+        for t in range(tokens.shape[1]):
+            h = self.step(h, tokens[:, t])
+        return h
+
+    def next_token_probs(self, h: np.ndarray) -> np.ndarray:
+        logits = h @ self.emb.T
+        p = np.exp(logits - logits.max(axis=-1, keepdims=True))
+        return p / p.sum(axis=-1, keepdims=True)
+
+
 async def main_async(ckpt: str = None, resume: bool = False,
                      flight_dump: str = None, profile_dir: str = None):
-    cfg = get_config("qwen3-0.6b").reduced()
-    srv = Server(cfg, batch=1, max_seq=64, seed=0)
     rng = np.random.default_rng(0)
+    lm = ToyLM(rng)
 
     # ---- build a toy datastore: (hidden-state key, next token value) ----
     n_store = 256
-    store_tokens = rng.integers(0, cfg.vocab_size, (n_store, 9)).astype(np.int32)
-    batch = {"tokens": jnp.asarray(store_tokens[:, :-1])}
-    hidden, _ = M.hidden_states(srv.params, cfg, batch)
-    keys = np.asarray(hidden[:, -1]).astype(np.float32)        # (N, d)
+    store_tokens = rng.integers(0, lm.vocab_size, (n_store, 9)).astype(np.int32)
+    keys = lm.hidden_states(store_tokens[:, :-1])               # (N, d)
     values = store_tokens[:, -1]                                # next tokens
     datastore = sparsify(keys)
 
@@ -107,11 +130,11 @@ async def main_async(ckpt: str = None, resume: bool = False,
         return sparsify((base + 0.1 * rng.standard_normal(base.shape))[None, :])
 
     # ---- serve one request with kNN interpolation -----------------------
-    prompt = rng.integers(0, cfg.vocab_size, 8).astype(np.int32)
-    req = Request(0, prompt, max_new=8)
-    assert srv.admit(req)
+    prompt = rng.integers(0, lm.vocab_size, 8).astype(np.int32)
+    max_new = 8
+    h = lm.hidden_states(prompt[None])                         # prefill
+    generated = [int(lm.next_token_probs(h)[0].argmax())]
     step = 0
-    generated = [req.out[-1]]
 
     # observability: a private flight recorder holds the serve→store span
     # timeline (dumped as JSONL with --flight-dump); --profile arms a
@@ -121,19 +144,11 @@ async def main_async(ckpt: str = None, resume: bool = False,
     sched = KNNScheduler(store, ServeConfig(r_block=8, window_s=0.005),
                          recorder=recorder, profile=profile)
     async with sched:
-        while srv.occupancy():
-            s = 0  # single slot
-            logits, cache = srv.decode(
-                srv.params, jnp.asarray(srv.slot_tok[s:s + 1]),
-                srv.slot_cache[s], jnp.int32(srv.slot_pos[s]),
-            )
-            srv.slot_cache[s] = cache
-
-            # query = current hidden state ~ final logits pre-softmax proxy:
-            # recompute hidden for the query token (teacher-forced 1-step)
-            qtok = jnp.asarray(srv.slot_tok[s:s + 1])
-            qh, _ = M.hidden_states(srv.params, cfg, {"tokens": qtok})
-            query = sparsify(np.asarray(qh[:, -1]).astype(np.float32))
+        while len(generated) < max_new:
+            # decode step: consume the last token; the new hidden state is
+            # both the LM's next-token state and the retrieval query
+            h = lm.step(h, np.asarray(generated[-1:]))
+            query = sparsify(h)
 
             # the decode-step retrieval rides one coalesced batch with the
             # background users' requests — one store dispatch for all of them
@@ -144,7 +159,7 @@ async def main_async(ckpt: str = None, resume: bool = False,
             ids, scores = ids[0], scores[0]
             valid = scores > -np.inf
 
-            p_lm = np.asarray(jax.nn.softmax(logits[0, -1]))
+            p_lm = lm.next_token_probs(h)[0]
             p_knn = np.zeros_like(p_lm)
             if valid.any():
                 w = np.exp(scores[valid] - scores[valid].max())
@@ -156,9 +171,6 @@ async def main_async(ckpt: str = None, resume: bool = False,
                 p = p_lm
             nxt = int(p.argmax())
             generated.append(nxt)
-            srv.slot_tok[s, 0] = nxt
-            srv.slot_pos[s] += 1
-            req.out.append(nxt)
 
             # ---- mutate the datastore while serving --------------------
             # feed the fresh (key -> generated token) pair back with a TTL
@@ -175,9 +187,6 @@ async def main_async(ckpt: str = None, resume: bool = False,
                 await sched.mutate(
                     store.save_dirty, ckpt, {"knnlm_values": values})
             step += 1
-
-            if len(req.out) >= req.max_new:
-                srv.slot_req[s] = None
 
         # explicit eviction: drop the two lowest-id seed entries
         await sched.mutate(store.delete, [0, 1])

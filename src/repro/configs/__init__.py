@@ -1,4 +1,4 @@
-"""Architecture configs — one module per assigned architecture (+ the paper's own join config)."""
-from repro.configs.base import ModelConfig, REGISTRY, get_config, register, all_arch_names
+"""The paper's join problem sizes (§5): one ``JoinConfig`` per workload."""
+from repro.configs.paper_knn import SYNTHETIC, YEAST_WORM, JoinConfig
 
-__all__ = ["ModelConfig", "REGISTRY", "get_config", "register", "all_arch_names"]
+__all__ = ["JoinConfig", "SYNTHETIC", "YEAST_WORM"]

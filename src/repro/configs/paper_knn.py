@@ -1,6 +1,4 @@
-"""The paper's own workload configs: KNN join problem sizes (§5).
-
-Not a ModelConfig — join jobs are configured separately."""
+"""The paper's own workload configs: KNN join problem sizes (§5)."""
 import dataclasses
 
 

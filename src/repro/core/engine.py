@@ -1492,9 +1492,9 @@ def distributed_join(
     (core/ring.py) remains for ``dim_axis`` (dimension-sharded tensor
     parallelism), which the store does not cover yet, and for traced
     inputs: the store's build phase is host-driven (concrete block
-    padding and index assembly), so under ``jax.jit`` tracing — the
-    dry-run compiling the whole join as one program — the fully
-    traceable ring runs instead.
+    padding and index assembly), so under ``jax.jit`` tracing (the
+    whole join compiled as one program) the fully traceable ring runs
+    instead.
     """
     import math
 
@@ -1507,7 +1507,7 @@ def distributed_join(
     n_ring = math.prod(mesh.shape[a] for a in ring_axes)
     if dim_axis is not None or traced or n_s_valid < n_ring:
         # the store needs concrete data (host-driven build) and >= 1 row
-        # per shard; the ppermute ring covers tracing (the dry-run),
+        # per shard; the ppermute ring covers tracing,
         # dimension sharding, and degenerate tiny-S cases
         from repro.core.ring import _ring_join_impl
 

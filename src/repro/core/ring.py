@@ -37,7 +37,6 @@ changes *which* (B_r, B_s) pair is joined where/when.
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Optional, Sequence, Tuple
 
 import jax

@@ -8,9 +8,7 @@ reduction).  In both modes the S side is built once and ``--repeat N``
 replays the query against it — the serving shape — reporting per-query
 wall times plus the ``index_builds`` / ``device_dispatches`` counters
 (builds stay at the number of S blocks, dispatches at the number of R
-blocks, regardless of queries x shards).  The 512-chip configuration of
-the legacy ring join is exercised by the dry-run (`--dryrun`), which
-lowers and compiles the shard_map program on the production mesh.
+blocks, regardless of queries x shards).
 
   PYTHONPATH=src python -m repro.launch.join_job --nr 2000 --ns 4000 \
       --dim 10000 --k 5 --algorithm iiib --ring --data-par 4
@@ -39,11 +37,6 @@ def build_index(cfg: JoinConfig, S):
     return SparseKNNIndex.build(S, spec)
 
 
-def run_host(cfg: JoinConfig, R, S, stats=None):
-    """One-shot host join (build + single query)."""
-    return build_index(cfg, S).query(R, stats=stats).state
-
-
 def build_store(cfg: JoinConfig, S, num_shards: int):
     """Build the sharded datastore once (one device-resident index stack
     per shard; the serving shape's multi-device build phase)."""
@@ -55,43 +48,6 @@ def build_store(cfg: JoinConfig, S, num_shards: int):
         r_block=cfg.r_block, s_block=cfg.s_block, tile=cfg.tile,
     )
     return ShardedKNNStore.build(S, spec, num_shards=num_shards)
-
-
-def dryrun_ring(cfg: JoinConfig, multi_pod: bool = False):
-    """Lower + compile the ring join on the production mesh (no data)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.ring import ring_knn_join
-    from repro.launch.mesh import make_production_mesh
-    from repro.sparse.format import SparseBatch
-
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    n_ring = mesh.shape["data"] * mesh.shape.get("pod", 1)
-    f = cfg.nnz_mean * 2
-
-    def job(Ri, Rv, Rn, Si, Sv, Sn):
-        R = SparseBatch(indices=Ri, values=Rv, nnz=Rn, dim=cfg.dim)
-        S = SparseBatch(indices=Si, values=Sv, nnz=Sn, dim=cfg.dim)
-        ring_axes = ("pod", "data") if multi_pod else ("data",)
-        st = ring_knn_join(R, S, cfg.k, mesh, algorithm=cfg.algorithm,
-                           ring_axes=ring_axes, tile=cfg.tile)
-        return st.scores, st.ids
-
-    nr = -(-cfg.n_r // n_ring) * n_ring
-    ns = -(-cfg.n_s // n_ring) * n_ring
-    args = (
-        jax.ShapeDtypeStruct((nr, f), jnp.int32),
-        jax.ShapeDtypeStruct((nr, f), jnp.float32),
-        jax.ShapeDtypeStruct((nr,), jnp.int32),
-        jax.ShapeDtypeStruct((ns, f), jnp.int32),
-        jax.ShapeDtypeStruct((ns, f), jnp.float32),
-        jax.ShapeDtypeStruct((ns,), jnp.int32),
-    )
-    with mesh:
-        lowered = jax.jit(job).lower(*args)
-        compiled = lowered.compile()
-    return lowered, compiled
 
 
 def main(argv=None):

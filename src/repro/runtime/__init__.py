@@ -3,6 +3,5 @@ from repro.runtime.fault import (  # noqa: F401
     FaultSpec,
     RetryPolicy,
     ShardLostError,
-    Supervisor,
     corrupt_checkpoint_leaf,
 )

@@ -1,47 +1,27 @@
-"""Fault tolerance runtime: supervised step loop, retry policy, straggler
-mitigation.
+"""Fault tolerance runtime for the store and the serve scheduler: retry
+policy, call-level watchdog, replica health, fault injection.
 
-At 1000+ nodes the failure model is: (a) a host dies (coordinator raises a
-distributed-runtime error on the next collective), (b) a device wedges
-(XLA raises), (c) a host straggles (slow input or slow NIC).  The
-mitigations implemented here:
+The failure model is a device that wedges (XLA hangs or raises), a shard
+whose device state is lost, or a whole replica that dies.  The pieces:
 
-* ``Supervisor.run`` wraps the step loop.  On a retryable exception it
-  re-initializes the training state from the last valid checkpoint (the
-  manifest-verified ``latest_step``) and replays.  Because the data
-  pipeline is a pure function of (seed, step), replay is exact: no batch
-  is skipped or double-counted.  The restore path uses the elastic
-  ``shard_fn``, so recovery onto a *smaller* surviving mesh (lost pod) is
-  the same code path as same-size restart.
 * ``RetryPolicy`` bounds retries with (optionally jittered) exponential
-  backoff; a non-retryable error (assertion, NaN guard) propagates
-  immediately.  ``with_timeout`` is the reusable call-level watchdog
-  (the serve scheduler wraps each batch dispatch in it).
-* **Straggler levers** (documented here, wired where they act):
-  1. input prefetch depth ≥ 2 (data/pipeline.py) — a slow input host
-     overlaps with compute;
-  2. the ring join's threshold tightening is monotone, so a late shard
-     only ever *over*-prunes later, never corrupts (core/ring.py);
-  3. step-time watchdog: ``Supervisor.step_timeout`` aborts a wedged step
-     so the retry path takes over instead of hanging the whole job
-     (bounded staleness: the step is dropped and replayed after restore).
-* **NaN guard** — ``guard_finite`` turns a non-finite loss into an
-  immediate non-retryable error (bad data/overflow should fail loudly,
-  not silently corrupt the run).
+  backoff; ``with_timeout`` is the reusable call-level watchdog (the serve
+  scheduler wraps each batch dispatch in it).
+* ``ShardLostError`` / ``ReplicaLostError`` name what a failed dispatch
+  lost; ``ReplicaHealth`` is the replicated store's per-replica circuit
+  breaker.
+* ``FaultPlan`` / ``FaultSpec`` script deterministic faults that the store
+  consults on every dispatch; ``corrupt_checkpoint_leaf`` damages a
+  committed checkpoint for the restore paths.
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
 import time
-from typing import Any, Callable, Optional
+from typing import Callable, Optional
 
-import jax
 import numpy as np
-
-
-class NonRetryableError(RuntimeError):
-    pass
 
 
 class ShardLostError(RuntimeError):
@@ -65,12 +45,6 @@ class ReplicaLostError(RuntimeError):
     def __init__(self, replica: int, message: Optional[str] = None):
         super().__init__(message or f"replica {replica} lost")
         self.replica = replica
-
-
-def guard_finite(name: str, value) -> None:
-    v = np.asarray(jax.device_get(value))
-    if not np.all(np.isfinite(v)):
-        raise NonRetryableError(f"non-finite {name}: {v!r}")
 
 
 @dataclasses.dataclass
@@ -108,7 +82,7 @@ def with_timeout(fn: Callable, timeout_s: Optional[float], *args, **kwargs):
     must not hang the whole serving loop — the caller's RetryPolicy takes
     over instead.  The abandoned call keeps running on its daemon thread
     (XLA dispatches are not interruptible); this bounds *caller* latency,
-    the same trade ``Supervisor.step_timeout`` makes for training steps.
+    not the device's work.
     """
     if timeout_s is None:
         return fn(*args, **kwargs)
@@ -336,82 +310,3 @@ def corrupt_checkpoint_leaf(directory: str, step: Optional[int] = None,
         f.write(bytes(data))
     return fp
 
-
-class _Watchdog:
-    """Raises in the main thread flow by flagging; checked between steps."""
-
-    def __init__(self, timeout_s: Optional[float]):
-        self.timeout_s = timeout_s
-        self._armed_at: Optional[float] = None
-        self._lock = threading.Lock()
-
-    def arm(self):
-        with self._lock:
-            self._armed_at = time.monotonic()
-
-    def disarm(self):
-        with self._lock:
-            self._armed_at = None
-
-    def expired(self) -> bool:
-        if self.timeout_s is None:
-            return False
-        with self._lock:
-            return (
-                self._armed_at is not None
-                and time.monotonic() - self._armed_at > self.timeout_s
-            )
-
-
-class Supervisor:
-    """Run ``step_fn`` from ``start_step`` to ``num_steps`` with restart-on-failure.
-
-    step_fn(step) -> metrics (host-visible after the call).
-    restore_fn(reason) -> new start step (reloads state from checkpoint).
-    """
-
-    def __init__(
-        self,
-        step_fn: Callable[[int], Any],
-        restore_fn: Callable[[str], int],
-        policy: RetryPolicy = RetryPolicy(),
-        step_timeout_s: Optional[float] = None,
-        on_metrics: Optional[Callable[[int, Any], None]] = None,
-    ):
-        self.step_fn = step_fn
-        self.restore_fn = restore_fn
-        self.policy = policy
-        self.watchdog = _Watchdog(step_timeout_s)
-        self.on_metrics = on_metrics
-        self.failures = 0
-
-    def run(self, start_step: int, num_steps: int) -> int:
-        step = start_step
-        # The retry budget is per-INCIDENT, not per-run: a successful step
-        # resets it, so two unrelated failures hours apart each get the
-        # full backoff schedule instead of exhausting a shared global one.
-        delays = None
-        while step < num_steps:
-            try:
-                self.watchdog.arm()
-                metrics = self.step_fn(step)
-                self.watchdog.disarm()
-                if self.on_metrics is not None:
-                    self.on_metrics(step, metrics)
-                step += 1
-                delays = None
-            except NonRetryableError:
-                raise
-            except Exception as e:  # noqa: BLE001 — device/runtime errors
-                self.failures += 1
-                if delays is None:
-                    delays = iter(self.policy.delays())
-                try:
-                    delay = next(delays)
-                except StopIteration:
-                    raise RuntimeError(
-                        f"step {step}: retries exhausted after {self.failures} failures"
-                    ) from e
-                time.sleep(delay)
-                step = self.restore_fn(f"{type(e).__name__}: {e}")
-        return step

@@ -13,7 +13,7 @@
                  carrying ``missing_shards`` when served degraded.
   QueueFull    — admission-control bounce carrying ``retry_after_s``.
 """
-from repro.serve.metrics import RollingWindow, ServeMetrics, percentiles
+from repro.serve.metrics import RollingWindow, ServeMetrics
 from repro.serve.scheduler import (
     KNNScheduler,
     QueueFull,
@@ -28,5 +28,4 @@ __all__ = [
     "ServeConfig",
     "ServeResult",
     "ServeMetrics",
-    "percentiles",
 ]

@@ -36,20 +36,6 @@ import numpy as np
 from repro.obs.registry import DEFAULT_TIME_BUCKETS_S, MetricRegistry
 
 
-def percentiles(samples, points=(50.0, 99.0)) -> Dict[str, Optional[float]]:
-    """``{"p50": ..., "p99": ...}`` over ``samples`` (None when empty).
-
-    Shared with ``launch/serve.py``'s per-request token-serving summary —
-    one definition of "p99" across both serving front-ends.
-    """
-    out: Dict[str, Optional[float]] = {}
-    arr = np.asarray(list(samples), dtype=np.float64)
-    for p in points:
-        key = f"p{p:g}"
-        out[key] = float(np.percentile(arr, p)) if arr.size else None
-    return out
-
-
 class RollingWindow:
     """Bounded sample window with percentile queries.
 
@@ -364,9 +350,8 @@ class ServeMetrics:
         }
 
     def faults(self) -> dict:
-        """The ``summary()["faults"]`` section — THE fault-counter schema
-        both serving front-ends print (``launch/serve.py`` sources its
-        JSON from here too, so the shapes cannot drift)."""
+        """The ``summary()["faults"]`` section: the fault-counter schema
+        the serving benches and smokes print."""
         return {
             "timeouts": self.timeouts,
             "retries": self.retries,

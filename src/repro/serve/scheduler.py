@@ -3,8 +3,8 @@
 The store (DESIGN.md §7) answers one R block per ``shard_map`` dispatch,
 but real traffic is millions of users each submitting a *few* sparse
 rows — at batch size 1 the paper's block-geometry wins (C2/C3 cost
-model) are wasted.  This is the LLM-serving continuous-batching pattern
-(``launch/serve.py``'s token server, transplanted to the query side):
+model) are wasted.  This is the LLM-serving continuous-batching pattern,
+transplanted to the query side:
 
 * ``submit(rows, k, deadline)`` — an awaitable that admits a request
   into a bounded queue and resolves to its ``(ids, scores)`` once a

@@ -9,7 +9,7 @@ MapReduce"): partition S row-wise, join every query block against every
 partition, merge per-partition top-k.  Here that becomes:
 
 * **Shard layout** — S is split into contiguous row ranges, one per
-  position of a mesh axis (``launch/mesh.make_store_mesh`` or any axis of
+  position of a mesh axis (``store/mesh.make_store_mesh`` or any axis of
   an existing mesh).  Each shard builds its own device-resident
   :class:`~repro.core.engine.SparseKNNIndex` structures ONCE — the padded
   CSR blocks (BF), tile-inverted indexes (IIB) or threshold-independent
@@ -17,12 +17,12 @@ partition, merge per-partition top-k.  Here that becomes:
   dim-frequency-rank order so every shard prunes like the single-device
   build over the concatenated S).  The per-shard stacks are assembled
   into ``(num_shards, blocks, ...)`` arrays placed with the leading axis
-  sharded (``launch/sharding.store_stack_specs``) — shard i's stacks
+  sharded (``store/mesh.store_stack_specs``) — shard i's stacks
   live on device i.
 
 * **Replicas** — ``make_store_mesh(..., replicas=)`` adds a ``replica``
   axis; the store splits it into per-replica sub-meshes
-  (``launch/mesh.replica_submeshes``) and places the SAME stacks on each
+  (``store/mesh.replica_submeshes``) and places the SAME stacks on each
   (the host mirror is the single source of truth; device replicas are a
   pure function of it).  Each fan-out dispatch routes to exactly one
   replica — half-open probes first, then live clean replicas round-robin
@@ -57,7 +57,7 @@ partition, merge per-partition top-k.  Here that becomes:
 * **Mutability** — ``add()`` appends a batch to the shard with the
   fewest live rows (balance policy), assigning fresh global ids and
   re-assembling only that shard's tail blocks; placement is INCREMENTAL
-  (``launch/sharding.store_shard_update``): while the padded stack
+  (``store/mesh.store_shard_update``): while the padded stack
   geometry is unchanged, only the touched shard's slice ships
   host→device — ``StoreStats.placed_shards``/``placed_bytes`` make it
   observable — and only a grown geometry (more blocks, wider bound)
@@ -116,6 +116,13 @@ from repro.obs import recorder as obs_recorder
 from repro.obs import trace as obs_trace
 from repro.runtime.fault import ReplicaHealth, ReplicaLostError, ShardLostError
 from repro.sparse.format import SparseBatch, num_tiles
+from repro.store.mesh import (
+    make_store_mesh,
+    replica_submeshes,
+    store_put,
+    store_shard_update,
+    submesh_compiler_options,
+)
 
 P = jax.sharding.PartitionSpec
 
@@ -216,8 +223,6 @@ def fanout_program(algorithm: str, mesh, axes: Tuple[str, ...], *, rb: int,
     into the shard's valid mask — still ONE dispatch per R block; the
     live-candidate counts ride back via ``all_gather``.  Arguments follow
     :meth:`ShardedKNNStore._fanout_args`."""
-    from repro.launch.mesh import submesh_compiler_options
-
     alg, sb = algorithm, s_block
     nsh = int(np.prod([mesh.shape[a] for a in axes]))
     rep = P()
@@ -403,8 +408,6 @@ class ShardedKNNStore:
         if spec.warm_start:
             raise ValueError("warm_start is not supported by ShardedKNNStore yet")
         if mesh is None:
-            from repro.launch.mesh import make_store_mesh
-
             mesh = make_store_mesh(num_shards, replicas=replicas)
         self.mesh = mesh
         names = tuple(mesh.axis_names)
@@ -420,8 +423,6 @@ class ShardedKNNStore:
         # replica; a single-replica store's "sub-mesh" is the mesh itself,
         # so the unreplicated path is byte-for-byte the old one
         if "replica" in names and "replica" not in self._axes:
-            from repro.launch.mesh import replica_submeshes
-
             self._replica_meshes = replica_submeshes(mesh)
         else:
             self._replica_meshes = [mesh]
@@ -794,8 +795,6 @@ class ShardedKNNStore:
                 self._place_replica_shards(r, sorted(touched))
 
     def _place_replica_full(self, r: int):
-        from repro.launch.sharding import store_put
-
         # host arrays straight into the sharded put: each device receives
         # only its own shard's slice (a device array made first would land
         # whole on the default device)
@@ -807,8 +806,6 @@ class ShardedKNNStore:
             int(v.size) * v.dtype.itemsize for v in tree.values())
 
     def _place_replica_shards(self, r: int, shards: Sequence[int]):
-        from repro.launch.sharding import store_shard_update
-
         st = dict(self._stacks[r])
         valid = self._replica_valid(r)
         for i in shards:
@@ -822,8 +819,6 @@ class ShardedKNNStore:
 
     def _refresh_replica_valid(self, r: int):
         """Re-place ONLY replica r's valid mask (tombstones / lost folds)."""
-        from repro.launch.sharding import store_put
-
         new_valid = store_put(
             self._replica_valid(r), self._replica_meshes[r], self._axes,
         )

@@ -1,7 +1,7 @@
 """Shared test fixtures.
 
 NOTE: no XLA_FLAGS here — smoke tests must see the real single CPU device.
-Multi-device tests (ring join, sharded train, mini dry-run) spawn
+Multi-device tests (ring join, sharded store, replicas) spawn
 subprocesses that set ``--xla_force_host_platform_device_count`` before
 importing jax (see tests/util_subproc.py).
 """

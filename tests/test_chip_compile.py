@@ -92,7 +92,7 @@ def test_store_iiib_fanout_compiles_for_v5e(topo, monkeypatch, n_shards):
     reduction, for one 2,048-row R block — on one chip, and on one replica
     (2 chips) of a 2x2 replicated store, which compiles without the
     slice-wide launch barrier."""
-    from repro.launch.mesh import submesh_compiler_options
+    from repro.store.mesh import submesh_compiler_options
 
     mesh = Mesh(np.array(topo.devices[:n_shards]), ("shard",))
     # the program sees the whole described slice, as on a four-chip host

@@ -1,104 +1,17 @@
-"""Fault-tolerance runtime: supervisor retries, NaN guard, watchdog arming,
-fault-injection primitives (FaultPlan / ShardLostError)."""
+"""Fault-tolerance runtime: retry policy, call-level watchdog, replica
+health, fault-injection primitives (FaultPlan / ShardLostError)."""
 import time
 
-import numpy as np
 import pytest
 
 from repro.runtime.fault import (
     FaultPlan,
     FaultSpec,
-    NonRetryableError,
     ReplicaHealth,
     ReplicaLostError,
     RetryPolicy,
     ShardLostError,
-    Supervisor,
-    guard_finite,
 )
-
-
-def test_supervisor_happy_path():
-    seen = []
-    sup = Supervisor(lambda i: seen.append(i), lambda r: 0,
-                     RetryPolicy(max_retries=0, backoff_s=0))
-    assert sup.run(0, 5) == 5
-    assert seen == [0, 1, 2, 3, 4]
-
-
-def test_supervisor_retries_and_restores():
-    calls = {"n": 0}
-    restores = []
-
-    def step(i):
-        calls["n"] += 1
-        if i == 3 and not restores:
-            raise RuntimeError("simulated device failure")
-
-    def restore_fn(reason):
-        restores.append(reason)
-        return 2  # last checkpoint at step 2
-
-    sup = Supervisor(step, restore_fn, RetryPolicy(max_retries=2, backoff_s=0.01))
-    assert sup.run(0, 6) == 6
-    assert len(restores) == 1
-    assert "simulated device failure" in restores[0]
-    assert sup.failures == 1
-    # steps 2..3 replayed
-    assert calls["n"] == 6 + 2
-
-
-def test_supervisor_exhausts_retries():
-    def step(i):
-        raise RuntimeError("always fails")
-
-    sup = Supervisor(step, lambda r: 0, RetryPolicy(max_retries=2, backoff_s=0.0))
-    with pytest.raises(RuntimeError, match="retries exhausted"):
-        sup.run(0, 3)
-
-
-def test_supervisor_retry_budget_is_per_incident():
-    """Regression: the retry budget must reset on step success.  The old
-    code materialized ``policy.delays()`` once per ``run``, so a second
-    unrelated incident inherited a part-spent (or empty) budget and blew
-    up with "retries exhausted" even though it was the first failure of
-    its own incident."""
-    fail_at = {2: 1, 4: 1}          # two incidents, one failure each
-
-    def step(i):
-        if fail_at.get(i, 0):
-            fail_at[i] -= 1
-            raise RuntimeError(f"incident@{i}")
-
-    restores = []
-
-    def restore_fn(reason):
-        restores.append(reason)
-        return int(reason.rsplit("@", 1)[1])   # replay the failed step
-
-    # max_retries=1: each incident needs (and gets) the full one-delay
-    # budget; a shared per-run iterator would StopIteration on incident 2
-    sup = Supervisor(step, restore_fn, RetryPolicy(max_retries=1, backoff_s=0.0))
-    assert sup.run(0, 6) == 6
-    assert sup.failures == 2
-    assert len(restores) == 2
-
-
-def test_nonretryable_propagates():
-    def step(i):
-        raise NonRetryableError("NaN loss")
-
-    sup = Supervisor(step, lambda r: 0, RetryPolicy(max_retries=5, backoff_s=0.0))
-    with pytest.raises(NonRetryableError):
-        sup.run(0, 3)
-
-
-def test_guard_finite():
-    guard_finite("ok", np.float32(1.0))
-    with pytest.raises(NonRetryableError):
-        guard_finite("bad", np.float32(np.nan))
-    with pytest.raises(NonRetryableError):
-        guard_finite("bad", np.array([1.0, np.inf]))
 
 
 def test_retry_policy_delays_reiterable_and_exponential():

@@ -221,7 +221,7 @@ def test_store_refreeze_matches_and_multi_axis_mesh():
     ring join's configuration)."""
     out = run_with_devices("""
 import numpy as np
-from repro.launch.mesh import make_mesh
+from repro.store.mesh import make_mesh
 from repro.sparse.datagen import synthetic_sparse
 from repro.core.engine import JoinSpec
 from repro.store import ShardedKNNStore
@@ -253,7 +253,7 @@ def test_traced_ring_join_lowers_via_legacy_ring():
     back to the fully-traceable ppermute ring for abstract inputs."""
     out = run_with_devices("""
 import jax, jax.numpy as jnp
-from repro.launch.mesh import make_mesh
+from repro.store.mesh import make_mesh
 from repro.core.ring import ring_knn_join
 from repro.sparse.format import SparseBatch
 

@@ -15,7 +15,7 @@ pytestmark = [pytest.mark.slow, pytest.mark.subproc]
 _PRELUDE = r"""
 import numpy as np
 from repro.core.engine import SparseKNNIndex, JoinSpec, JoinStats
-from repro.launch.mesh import make_store_mesh
+from repro.store.mesh import make_store_mesh
 from repro.runtime.fault import FaultPlan, FaultSpec, ReplicaHealth
 from repro.sparse.datagen import synthetic_sparse
 from repro.store import ShardedKNNStore

@@ -19,15 +19,3 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 560) -> str:
     assert proc.returncode == 0, f"subprocess failed:\nSTDOUT:{proc.stdout}\nSTDERR:{proc.stderr}"
     return proc.stdout
 
-
-def run_module(args: list[str], n_devices: int = 0, timeout: int = 560) -> str:
-    env = dict(os.environ)
-    if n_devices:
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    proc = subprocess.run(
-        [sys.executable, "-m"] + args,
-        capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO,
-    )
-    assert proc.returncode == 0, f"{args} failed:\nSTDOUT:{proc.stdout}\nSTDERR:{proc.stderr}"
-    return proc.stdout
